@@ -34,18 +34,16 @@ if __name__ == "__main__":
     ckpt_root = tempfile.mkdtemp(prefix="serve_adapted_")
     print(f"== meta-train {args.train_steps} steps -> checkpoint "
           f"({ckpt_root}) ==")
-    sys.argv = ["train", "--arch", args.arch, "--reduced",
+    train_main(["--arch", args.arch, "--reduced",
                 "--steps", str(args.train_steps), "--seq", "16",
                 "--global-batch", "16", "--agents", "4",
                 "--seed", str(args.seed), "--ckpt-dir", ckpt_root,
-                "--run-log", os.path.join(ckpt_root, "run.jsonl")]
-    train_main()
+                "--run-log", os.path.join(ckpt_root, "run.jsonl")])
 
     print("== adapt the checkpoint centroid to an unseen domain, "
           "then serve ==")
-    sys.argv = ["serve", "--arch", args.arch, "--reduced",
+    serve_main(["--arch", args.arch, "--reduced",
                 "--seed", str(args.seed),
                 "--ckpt-dir", os.path.join(ckpt_root, f"seed{args.seed}"),
                 "--batch", "4", "--prompt-len", "8", "--gen", "16",
-                "--adapt-steps", "2"] + rest
-    serve_main()
+                "--adapt-steps", "2"] + rest)

@@ -52,6 +52,32 @@ def parse_computations(hlo: str) -> tuple[dict[str, list[str]], str | None]:
     return comps, entry
 
 
+_DEF_RE = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+\[[^\]]*\])")
+
+
+def instruction_shapes(comps: dict[str, list[str]]) -> dict[str, str]:
+    """``{instruction name: array type}`` (e.g. ``'f32[6,6]'``) over every
+    computation.  Instruction names are unique within a module, and the
+    printed HLO names an operand without its type, so a rule that asks
+    what an operand is looks its name up here."""
+    shapes: dict[str, str] = {}
+    for body in comps.values():
+        for line in body:
+            m = _DEF_RE.match(line)
+            if m:
+                shapes[m.group(1)] = m.group(2)
+    return shapes
+
+
+def operand_names(line: str, op: str) -> list[str]:
+    """Operand names of the ``op(...)`` call on one instruction line."""
+    m = re.search(rf"\b{re.escape(op)}\(([^)]*)\)", line)
+    if not m:
+        return []
+    return [tok.split()[-1].lstrip("%") for tok in m.group(1).split(",")
+            if tok.strip()]
+
+
 def conditional_branches(line: str) -> list[str]:
     """Branch computation names of one ``conditional(...)`` instruction."""
     branches: list[str] = []

@@ -330,14 +330,18 @@ def _wire_dtype_leak(ctx: LintContext) -> list[Finding]:
 # ---------------------------------------------------------------------------
 
 
-def _marker_lines(lines: list[str], K: int, wire_dtype: str | None) -> list[str]:
-    """Instructions that implement the combine: the K×K mixing dot, and
-    (on a bf16 wire) u16 collective-permutes — nothing else in the
-    program produces either."""
-    dot_re = re.compile(rf"(?:f32|bf16|f64)\[{K},{K}\]")
+def _marker_lines(lines: list[str], K: int, wire_dtype: str | None,
+                  shapes: dict[str, str]) -> list[str]:
+    """Instructions that implement the combine: a dot with a K×K float
+    operand (the mixing matrix, looked up in ``shapes`` by name), and (on a
+    bf16 wire) u16 collective-permutes — nothing else in the program
+    produces either."""
+    mix = re.compile(rf"(?:f32|bf16|f64)\[{K},{K}\]$")
     out = []
     for line in lines:
-        if " dot(" in line and dot_re.search(line):
+        if " dot(" in line and any(
+                mix.match(shapes.get(name, ""))
+                for name in H.operand_names(line, "dot")):
             out.append(line)
         elif (
             wire_dtype == "bfloat16"
@@ -358,10 +362,11 @@ def _conditional_comm(ctx: LintContext) -> list[Finding]:
     comps, entry = H.parse_computations(ctx.hlo or "")
     if entry is None:
         entry = max(comps, key=lambda c: len(comps[c])) if comps else ""
+    shapes = H.instruction_shapes(comps)
     marked = {
         name
         for name, lines in comps.items()
-        if _marker_lines(lines, ctx.K, ctx.wire_dtype)
+        if _marker_lines(lines, ctx.K, ctx.wire_dtype, shapes)
     }
     findings: list[Finding] = []
     if not marked:

@@ -1,23 +1,8 @@
-"""Version-adaptive JAX wrappers.
+"""Thin wrappers over the JAX APIs the sharded paths share.
 
-The repo supports jax 0.4.x (tested on 0.4.37) and jax >= 0.5.  The two
-lines differ in exactly the APIs the sharded combine path needs:
-
-=====================  ==============================  =========================
-capability             jax 0.4.x                       jax >= 0.5
-=====================  ==============================  =========================
-shard_map              ``jax.experimental.shard_map    ``jax.shard_map(...,
-                       .shard_map(..., check_rep=,     axis_names=, check_vma=)``
-                       auto=frozenset)``
-AbstractMesh           ``AbstractMesh(((name, size),   ``AbstractMesh(sizes,
-                       ...))`` — pair tuples           names)`` — parallel tuples
-jax.make_mesh          no ``axis_types`` kwarg         ``axis_types`` kwarg
-=====================  ==============================  =========================
-
-Everything that touches one of these goes through this module so the rest
-of the codebase is version-agnostic.  All wrappers are thin: they resolve
-the API shape once (cheap feature probes, no version-string parsing beyond
-the exported ``JAX_VERSION`` convenience) and delegate.
+The repo targets the installed JAX (0.9).  Every shard_map, mesh and
+cost-analysis call goes through this module, so a later change of those
+APIs is made in one place.
 """
 from __future__ import annotations
 
@@ -26,31 +11,12 @@ from typing import Any, Callable, Iterable, Sequence
 import jax
 
 __all__ = [
-    "JAX_VERSION",
     "shard_map",
     "abstract_mesh",
     "make_mesh",
     "mesh_axis_sizes",
     "cost_analysis",
-    "tree_map",
-    "tree_leaves",
-    "tree_structure",
-    "tree_flatten",
-    "tree_unflatten",
 ]
-
-
-def _parse_version(v: str) -> tuple[int, ...]:
-    parts = []
-    for p in v.split("."):
-        digits = "".join(ch for ch in p if ch.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts) or (0,)
-
-
-JAX_VERSION: tuple[int, ...] = _parse_version(jax.__version__)
 
 
 # ---------------------------------------------------------------------------
@@ -62,21 +28,14 @@ def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any, *,
               check: bool = False) -> Callable:
     """Partial-manual shard_map over ``axis_names`` (all mesh axes if None).
 
-    ``check`` maps to ``check_vma`` (new API) / ``check_rep`` (old API).
-    Axes not in ``axis_names`` stay automatic: on the old API they are
-    passed through ``auto=``, on the new API they are simply omitted from
-    ``axis_names``.
+    ``check`` maps to ``check_vma``.  Axes not in ``axis_names`` stay
+    automatic.
     """
-    manual = (frozenset(axis_names) if axis_names is not None
-              else frozenset(mesh.axis_names))
-    if hasattr(jax, "shard_map"):                       # jax >= 0.5
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map  # 0.4.x
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
+    manual = (set(axis_names) if axis_names is not None
+              else set(mesh.axis_names))
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check)
 
 
 # ---------------------------------------------------------------------------
@@ -85,67 +44,27 @@ def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any, *,
 
 def abstract_mesh(axis_shapes: Sequence[int],
                   axis_names: Sequence[str]) -> Any:
-    """``AbstractMesh`` for both constructor generations.
-
-    jax >= 0.5 takes parallel ``(sizes, names)`` tuples; jax 0.4.x takes a
-    single tuple of ``(name, size)`` pairs.
-    """
+    """``AbstractMesh`` from parallel ``(sizes, names)`` tuples."""
     from jax.sharding import AbstractMesh
-    axis_shapes = tuple(int(s) for s in axis_shapes)
-    axis_names = tuple(axis_names)
-    try:
-        return AbstractMesh(axis_shapes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return AbstractMesh(tuple(int(s) for s in axis_shapes), tuple(axis_names))
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               **kwargs: Any) -> Any:
-    """``jax.make_mesh`` that tolerates the missing ``axis_types`` kwarg on
-    jax 0.4.x (where every axis is implicitly automatic anyway)."""
-    axis_shapes = tuple(int(s) for s in axis_shapes)
+    """``jax.make_mesh`` with every axis automatic unless ``axis_types``
+    says otherwise."""
     axis_names = tuple(axis_names)
-    if "axis_types" not in kwargs and hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (
-            (jax.sharding.AxisType.Auto,) * len(axis_names))
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    except TypeError:
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(tuple(int(s) for s in axis_shapes), axis_names,
+                         **kwargs)
 
 
 def mesh_axis_sizes(mesh: Any) -> dict[str, int]:
     """{axis name: size} for ``Mesh`` and ``AbstractMesh`` alike."""
-    if hasattr(mesh, "axis_sizes"):
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    if hasattr(mesh, "shape_tuple"):
-        return {name: int(size) for name, size in mesh.shape_tuple}
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def cost_analysis(compiled: Any) -> dict:
-    """``compiled.cost_analysis()`` normalized across versions: newer jax
-    returns a flat dict, 0.4.x returns a one-element list of dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
-# ---------------------------------------------------------------------------
-# Tree utilities (jax.tree module appeared mid-0.4.x; fall back to tree_util)
-# ---------------------------------------------------------------------------
-
-def _tree_api(name: str) -> Callable:
-    tree_mod = getattr(jax, "tree", None)
-    if tree_mod is not None and hasattr(tree_mod, name):
-        return getattr(tree_mod, name)
-    return getattr(jax.tree_util, f"tree_{name}")
-
-
-tree_map = _tree_api("map")
-tree_leaves = _tree_api("leaves")
-tree_structure = _tree_api("structure")
-tree_flatten = _tree_api("flatten")
-tree_unflatten = _tree_api("unflatten")
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())

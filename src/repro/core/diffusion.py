@@ -31,8 +31,7 @@ Registered backends
                  one-agent-per-shard.
 ``mesh_sparse``  production sparse combine: the ``sparse`` schedule wrapped
                  in a partial-manual shard_map over the agent mesh axis
-                 (built via :mod:`repro.compat`, so it runs on jax 0.4.x
-                 and >= 0.5 alike).  Requires jit.
+                 (built via :mod:`repro.compat`).  Requires jit.
 ``sparse_host_dynamic``
                  host-roll lowering of a *dynamic* (stacked ``(S, K, K)``)
                  schedule via its :class:`repro.core.topology.ScheduleIR`:
@@ -139,10 +138,6 @@ sparse backend (``sparse``/``sparse_host``/``mesh_sparse``) to its
 rounds and wire cost are identical, only the weight gather becomes
 step-indexed — and only falls back to ``dense`` (loudly) for backends with
 no dynamic form.
-
-Supported JAX versions: 0.4.x (tested on 0.4.37) and >= 0.5 — every
-version-sensitive construct (shard_map flavor, AbstractMesh constructor)
-goes through :mod:`repro.compat`.
 """
 from __future__ import annotations
 
@@ -347,7 +342,7 @@ def make_mesh_sparse_combine(A: np.ndarray, mesh, axis_name: str,
     # stays auto (partial-manual mode — fine on TPU, but XLA:CPU cannot
     # partition it, so CPU callers should pass specs covering their axes).
     manual = {axis_name}
-    for s in compat.tree_leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
+    for s in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
         for part in s:
             if part is not None:
                 manual.update((part,) if isinstance(part, str) else part)
@@ -477,7 +472,7 @@ def make_mesh_sparse_dynamic_combine(ir, mesh, axis_name: str,
     inner = make_sparse_dynamic_combine(ir, axis_name, wire_dtype=wire_dtype)
     specs = in_specs if in_specs is not None else _P(axis_name)
     manual = {axis_name}
-    for s in compat.tree_leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
+    for s in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
         for part in s:
             if part is not None:
                 manual.update((part,) if isinstance(part, str) else part)

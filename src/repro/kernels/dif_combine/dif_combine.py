@@ -48,10 +48,10 @@ the ATC (``w' = A·(w + u)``), consensus (``w' = A·w + u``) or local
 moments while the mix degenerates to the identity.
 
 ``dif_combine`` is the original combine-only kernel (paper eq. 6b,
-``out[k, m] = Σ_l A[l, k]·φ[l, m]``): grid over (K, M/bm), one (K, bm)
-φ-tile read per output row — one pass over the parameter bytes instead of
-K−1 separate axpy passes, still used by the ``pallas`` combine backend and
-the ``cta`` pre-mix.
+``out[k, m] = Σ_l A[l, k]·φ[l, m]``): grid over M/bm, each (K, bm) φ-tile
+read once and mixed into all K output rows by one ``Aᵀ·φ`` contraction —
+one pass over the parameter bytes instead of K−1 separate axpy passes,
+used by the ``pallas`` combine backend and the ``cta`` pre-mix.
 
 Tiling: bm must be lane-aligned (multiple of 128) so reductions run on the
 VPU at full width; K rides the sublane dim (K ≥ 8 tiles exactly at f32).
@@ -70,12 +70,11 @@ _MODES = ("atc", "consensus", "local")
 
 
 def _combine_kernel(a_ref, phi_ref, out_ref):
-    k = pl.program_id(0)
-    w = jax.lax.dynamic_slice_in_dim(a_ref[...], k, 1, axis=1)   # (K, 1)
-    phi = phi_ref[...]                                           # (K, bm)
-    acc = jnp.sum(phi.astype(jnp.float32) * w.astype(jnp.float32), axis=0,
-                  keepdims=True)                                 # (1, bm)
-    out_ref[...] = acc.astype(out_ref.dtype)
+    # every agent row of the tile at once: out[k] = Σ_l A[l, k] · phi[l]
+    mixed = jax.lax.dot_general(
+        a_ref[...].astype(jnp.float32), phi_ref[...].astype(jnp.float32),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    out_ref[...] = mixed.astype(out_ref.dtype)
 
 
 def _check_block(M: int, block_m: int) -> None:
@@ -101,15 +100,16 @@ def dif_combine(A: jax.Array, phi: jax.Array, *, block_m: int = 512,
             f"K={K} stacked agents of phi {phi.shape}; need A of "
             f"shape ({K}, {K})")
     _check_block(M, block_m)
-    grid = (K, M // block_m)
+    # the output block spans all K rows: a (1, bm) row block of a (K, M)
+    # array breaks the TPU's (8, 128) tiling rule unless K == 1
     return pl.pallas_call(
         _combine_kernel,
-        grid=grid,
+        grid=(M // block_m,),
         in_specs=[
-            pl.BlockSpec((K, K), lambda k, m: (0, 0)),
-            pl.BlockSpec((K, block_m), lambda k, m: (0, m)),
+            pl.BlockSpec((K, K), lambda m: (0, 0)),
+            pl.BlockSpec((K, block_m), lambda m: (0, m)),
         ],
-        out_specs=pl.BlockSpec((1, block_m), lambda k, m: (k, m)),
+        out_specs=pl.BlockSpec((K, block_m), lambda m: (0, m)),
         out_shape=jax.ShapeDtypeStruct((K, M), phi.dtype),
         interpret=interpret,
     )(A, phi)
@@ -252,9 +252,13 @@ def fused_combine_update(table: jax.Array, sel: jax.Array, ctl: jax.Array,
     kernel = functools.partial(_fused_kernel, mode=mode, kind=kind, lr=lr,
                                b1=b1, b2=b2, eps=eps,
                                weight_decay=weight_decay, beta=beta)
+    # each tile is read before it is written, so params and moments update
+    # in place: outputs alias inputs 4 (params) and 6.. (moments)
+    aliases = {4: 0, **{6 + i: 1 + i for i in range(n_mom)}}
     outs = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, input_output_aliases=aliases,
+        interpret=interpret,
     )(table, sel, ctl, scale, params, grads, *moments)
     outs = list(outs) + [None, None]
     return outs[0], outs[1] if n_mom >= 1 else None, \

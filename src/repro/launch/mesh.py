@@ -1,9 +1,11 @@
-"""Production mesh construction — the one owner of the mesh-axis contract.
+"""Mesh construction — the one owner of the mesh-axis contract.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state.  The dry-run entrypoint sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; everything else (tests, benches) sees the real single device.
+never touches jax device state.  ``make_host_mesh`` builds the trainer's
+mesh over the devices that are present.  ``make_production_mesh`` is the
+pod-scale mesh of the dry run and the compiled-program lint, whose
+entrypoints set ``XLA_FLAGS=--xla_force_host_platform_device_count=512``
+before any jax import.
 
 Mesh-axis contract
 ==================
@@ -37,7 +39,7 @@ configs (qwen2_7b, mixtral_8x22b, deepseek_v2_lite) run decentralized.
 """
 from __future__ import annotations
 
-import warnings
+from typing import Sequence
 
 import jax
 
@@ -84,38 +86,32 @@ def make_production_mesh(*, multi_pod: bool = False,
 
 
 def make_host_mesh(data: int = 1, model: int = 1, *,
-                   agents: int | None = None) -> jax.sharding.Mesh:
-    """Tiny mesh over whatever devices exist (tests / CPU examples).
+                   agents: int | None = None,
+                   devices: Sequence[jax.Device] | None = None
+                   ) -> jax.sharding.Mesh:
+    """Mesh over the devices that are present (``devices``, default every
+    device of the process) — what the trainer and the serve engine run on.
 
-    Legacy form: ``(data, model)``.  With ``agents=K``: the host-scale
-    equivalent of the agent-aware production mesh — ``(agent, data,
-    model)``, collapsing to ``(agent, model)`` when ``data == 1`` —
-    requiring ``K · data · model`` to divide the device count exactly
-    (agent-per-shard combine backends need the full extent, so a silent
-    clamp would change K under the caller).
-
-    A legacy request that does not factor over the available devices is
-    clamped as before, but now *loudly*: a RuntimeWarning reports the
-    requested and effective extents instead of silently dropping devices.
+    Legacy form: ``(data, model)``.  With ``agents=K``: ``(agent, data,
+    model)``, collapsing to ``(agent, model)`` when ``data == 1`` — one
+    agent per ``agent`` slice.  The product of the extents must divide the
+    device count; the mesh takes the first that many devices.  A geometry
+    that does not factor raises with both numbers: a clamp would change K
+    (or the batch split) under the caller.
     """
-    n = len(jax.devices())
-    if agents is not None:
-        if agents < 1 or data < 1 or model < 1 or n % (agents * data * model):
-            raise ValueError(
-                f"host agent mesh does not factor: agents={agents} × "
-                f"data={data} × model={model} = {agents * data * model} "
-                f"must divide the {n} available device(s)")
-        if data == 1:
-            return compat.make_mesh((agents, model), ("agent", "model"))
-        return compat.make_mesh((agents, data, model),
-                                ("agent", "data", "model"))
-    eff_data = min(data, n)
-    eff_model = max(1, min(model, n // eff_data))
-    if (eff_data, eff_model) != (data, model) or n % (eff_data * eff_model):
-        warnings.warn(
-            f"make_host_mesh(data={data}, model={model}) does not factor "
-            f"over the {n} available device(s); using "
-            f"(data={eff_data}, model={eff_model}) — "
-            f"{n - eff_data * eff_model} device(s) unused",
-            RuntimeWarning, stacklevel=2)
-    return compat.make_mesh((eff_data, eff_model), ("data", "model"))
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
+    extents = (agents or 1) * data * model
+    if min(agents or 1, data, model) < 1 or n % extents:
+        head = f"agents={agents} × " if agents is not None else ""
+        raise ValueError(
+            f"host mesh does not factor: {head}data={data} × model={model} "
+            f"= {extents} must divide the {n} available device(s)")
+    if agents is None:
+        return compat.make_mesh((data, model), ("data", "model"),
+                                devices=devices)
+    if data == 1:
+        return compat.make_mesh((agents, model), ("agent", "model"),
+                                devices=devices)
+    return compat.make_mesh((agents, data, model),
+                            ("agent", "data", "model"), devices=devices)

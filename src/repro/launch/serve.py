@@ -16,6 +16,9 @@ a JSONL run log.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \\
       --batch 4 --prompt-len 8 --gen 16 --adapt-steps 2 --seed 0 \\
       [--users 4 --rounds 2] [--ckpt-dir ckpts/seed0] [--run-log serve.jsonl]
+
+``main(argv)`` runs in the caller's process and returns a summary of the
+session, so a script that already holds the accelerator can drive it.
 """
 from __future__ import annotations
 
@@ -29,6 +32,7 @@ from repro.checkpoint import restore_centroid
 from repro.configs import get_config
 from repro.data.lm_tasks import LMTaskSource
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import ServeEngine
 
 
@@ -43,7 +47,12 @@ def make_support_source(cfg, seq_len: int, task_batch: int,
         n_domains=8, holdout_domains=2, seed=seed)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run one serving session; ``argv`` defaults to ``sys.argv[1:]``.
+
+    Returns ``{"rounds", "decode", "tokens", "cache"}``: the adapt metrics
+    of each round (hits, misses, seconds), the decode phase metrics, the
+    generated tokens and the cache counters."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -78,7 +87,8 @@ def main() -> None:
     ap.add_argument("--run-log", default=None,
                     help="JSONL path for the engine's kind=serve record "
                          "(cache counters, adapt p50/p99, per-phase tok/s)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -92,7 +102,11 @@ def main() -> None:
         cache_capacity=args.cache_capacity, rank=args.rank, dtype=dt)
 
     if args.ckpt_dir:
-        params = restore_centroid(args.ckpt_dir, engine.bundle.params_specs)
+        # the centroid in the engine's dtype (f32 for --reduced, whatever
+        # dtype the trainer stored)
+        like = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, dt),
+                            engine.bundle.params_specs)
+        params = restore_centroid(args.ckpt_dir, like)
         print(f"[serve] launch model = checkpoint centroid ({args.ckpt_dir})")
     else:
         params = engine.model.init(jax.random.key(args.seed), dt)
@@ -103,11 +117,12 @@ def main() -> None:
     # round (same eval seed → same domain draw), so rounds 2+ are the
     # recurring-user path and resolve from the adapted-state cache
     source = make_support_source(cfg, total, B, seed=args.seed)
-    ep = None
+    ep, rounds = None, []
     for rnd in range(args.rounds):
         ep = source.eval_sample(args.users, seed=args.seed, split=args.split)
         requests = engine.requests_from_episode(source, ep)
         adapted, m = engine.adapt(requests)
+        rounds.append(m)
         doms = np.asarray(ep.domains).tolist()
         print(f"[serve] round {rnd}: adapted {m['n']} users "
               f"(domains {doms}) in {m['seconds']:.3f}s — "
@@ -135,6 +150,7 @@ def main() -> None:
         log.write(**engine.log_record())
         log.close()
         print(f"[serve] run log -> {args.run_log}")
+    return {"rounds": rounds, "decode": dm, "tokens": tokens, "cache": stats}
 
 
 if __name__ == "__main__":

@@ -32,17 +32,35 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 # Agent / batch geometry
 # ---------------------------------------------------------------------------
 
-def agent_count(cfg: ArchConfig, mesh: Mesh) -> int:
-    """K for an arch on a mesh.  A first-class ``agent`` mesh axis defines
-    K outright; legacy meshes fall back to ``cfg.placement`` (one agent per
-    pod, or agents tiling the full data-parallel extent)."""
+def agent_count(cfg: ArchConfig, mesh: Mesh, agents: int | None = None
+                ) -> int:
+    """K for an arch on a mesh.
+
+    ``agents`` is the caller's K and is returned unchanged once the mesh can
+    hold it: a first-class ``agent`` axis must have extent K, and on legacy
+    meshes the axes the agents tile (``pod``/``data`` per
+    ``cfg.placement``) must divide K — several agents then stack on the
+    leading ``agent`` dimension of each device.  Anything else raises with
+    both numbers.  Without ``agents``, K is read off the mesh: the ``agent``
+    axis, or on legacy meshes one agent per pod, or agents tiling the full
+    data-parallel extent."""
     from repro.sharding.rules import _axis_sizes
     sizes = _axis_sizes(mesh)
     if "agent" in sizes:
-        return sizes["agent"]
-    if cfg.placement == "pod":
-        return sizes.get("pod", 1)
-    return sizes.get("data", 1) * sizes.get("pod", 1)
+        tiled = sizes["agent"]
+    elif cfg.placement == "pod":
+        tiled = sizes.get("pod", 1)
+    else:
+        tiled = sizes.get("data", 1) * sizes.get("pod", 1)
+    if agents is None:
+        return tiled
+    if agents < 1 or (agents != tiled if "agent" in sizes
+                      else agents % tiled):
+        raise ValueError(
+            f"K={agents} agents do not fit mesh {dict(sizes)}: "
+            + ("the agent axis must have extent K" if "agent" in sizes
+               else f"the {tiled} agent slice(s) of the mesh must divide K"))
+    return agents
 
 
 def batch_geometry(cfg: ArchConfig, shape: InputShape, K: int
@@ -356,7 +374,11 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
                 strategy: str | None = None,
                 schedule: str = "static",
                 link_failure_p: float = 0.2,
-                schedule_seed: int = 0) -> TrainBundle:
+                schedule_seed: int = 0,
+                agents: int | None = None) -> TrainBundle:
+    """Everything the trainer needs for one (arch × shape × mesh) meta step.
+    ``agents`` fixes K (see :func:`agent_count`); without it K is read off
+    the mesh."""
     shape = resolve_input_shape(shape_name)
     assert shape.kind in ("train", "prefill")
     dt = DTYPES[cfg.dtype]
@@ -375,7 +397,7 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
         # keep per-task activations batch-sharded over the data axis (the
         # agent/task dims are vmapped away above this constraint)
         model.act_sharding = NamedSharding(mesh, P("data", None, None))
-    K = agent_count(cfg, mesh)
+    K = agent_count(cfg, mesh, agents)
     T, tb = batch_geometry(cfg, shape, K)
     mcfg = meta_config_for(cfg, K, T, strategy=strategy, schedule=schedule,
                            link_failure_p=link_failure_p,

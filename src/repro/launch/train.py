@@ -1,8 +1,13 @@
 """Dif-MAML training driver.
 
-Runs the decentralized meta-training loop for any registered architecture.
-On real TPU slices this uses the production mesh; on CPU it falls back to a
-reduced config + host mesh so the same entrypoint exercises end-to-end.
+Runs the decentralized meta-training loop for any registered architecture
+on the devices that are present.  ``--agents K`` always means K agents: on
+fewer devices the K agent copies stack on the leading ``agent`` dimension
+of each device (dense or host-roll combine); ``--mesh-agents K`` puts one
+agent on each slice of an ``(agent[, data], model)`` mesh (ppermute
+combine).  ``--reduced`` cuts widths only; ``--seq``/``--global-batch`` (or
+``--shape``) set the geometry either way.  The pod-scale production mesh is
+the dry run's (``launch/dryrun.py``).
 
 Every run emits a JSONL run log (``--run-log``, default
 ``results/train_<arch>_seed<seed>.jsonl``): one ``{"kind": "train", ...}``
@@ -25,6 +30,9 @@ boundaries; C=1 reproduces the legacy per-step loop step-for-step.
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 20 \\
       --reduced --seq 64 --global-batch 16 --agents 4 --seed 1 \\
       --eval-every 10 --eval-tasks 8
+
+``main(argv)`` runs in the caller's process and returns a summary of the
+run, so a script that already holds the accelerator can drive it.
 """
 from __future__ import annotations
 
@@ -37,11 +45,12 @@ import time
 import jax
 
 from repro.checkpoint import save_checkpoint, restore_checkpoint, latest_step
-from repro.configs import INPUT_SHAPES, get_config, register_input_shape
+from repro.configs import INPUT_SHAPES, get_config
 from repro.configs.base import InputShape
 from repro.core import diffusion, topology, update
 from repro.data.lm_tasks import LMTaskSource
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.launch import steps as S
 
 
@@ -82,20 +91,37 @@ class RunLog:
         self._f.close()
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run one training job; ``argv`` defaults to ``sys.argv[1:]``.
+
+    Returns ``{"K", "loss", "disagreement", "step_s", "compile_s",
+    "tpu_custom_calls", "run_log"}``: per-step loss and disagreement,
+    per-step seconds of each dispatch (compilation excluded), the seconds
+    spent compiling, and how many Pallas TPU kernels the compiled step
+    holds."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--shape", default="train_4k")
+    ap.add_argument("--shape", default=None,
+                    help="registered input shape (e.g. train_4k); "
+                         "overrides --seq/--global-batch")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0,
                     help="run seed: threads through launch-model init, the "
                          "task source, and checkpoint naming (ckpt-dir/"
                          "seed<N>/) so independent runs never collide")
     ap.add_argument("--reduced", action="store_true",
-                    help="smoke-scale variant (CPU)")
+                    help="cut widths and depth to the smoke-test variant "
+                         "(ArchConfig.reduced); the geometry still comes "
+                         "from --seq/--global-batch or --shape")
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--global-batch", type=int, default=16)
-    ap.add_argument("--agents", type=int, default=4)
+    ap.add_argument("--agents", type=int, default=None,
+                    help="K agents (default 4, or --mesh-agents), whatever "
+                         "the device count: agents that outnumber the "
+                         "devices stack on each device's leading agent "
+                         "dimension")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="run on the first N devices (default: all)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=1)
@@ -110,15 +136,11 @@ def main() -> None:
     ap.add_argument("--run-log", default=None,
                     help="JSONL run log path (default results/"
                          "train_<arch>_seed<seed>.jsonl)")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--mesh-agents", type=int, default=None,
-                    help="build an agent-axis mesh (agent[, data], model) "
-                         "with this many agents instead of the legacy "
-                         "placement-driven meshes; each agent's parameter "
-                         "slice is itself TP/FSDP-sharded. With --reduced "
-                         "the host-mesh equivalent is built over the "
-                         "available devices (count must be divisible by "
-                         "the agent count)")
+                    help="K agents, one per slice of an (agent, model) mesh "
+                         "over the devices; the leftover device factor is "
+                         "tensor parallelism (the device count must be a "
+                         "multiple of K)")
     ap.add_argument("--prefetch", type=int, default=2,
                     help="meta-batch pipeline depth (0 = sample "
                          "synchronously on the step loop)")
@@ -160,13 +182,18 @@ def main() -> None:
                          "defaults to bfloat16 when the outer dtype is "
                          "bfloat16 (f32 escape hatch: --combine-dtype "
                          "float32)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.fused_outer:
         if args.combine not in (None, "fused"):
             ap.error(f"--fused-outer conflicts with --combine "
                      f"{args.combine}: the fused outer step IS the combine "
                      f"backend")
         args.combine = "fused"
+    if args.mesh_agents and args.agents not in (None, args.mesh_agents):
+        ap.error(f"--agents {args.agents} conflicts with --mesh-agents "
+                 f"{args.mesh_agents}")
+    K = args.mesh_agents or args.agents or 4
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.outer_dtype or args.combine_dtype:
@@ -175,23 +202,18 @@ def main() -> None:
             combine_dtype=args.combine_dtype or cfg.combine_dtype)
     if args.reduced:
         cfg = cfg.reduced()
-        shape = InputShape("custom", args.seq, args.global_batch, "train")
-        if args.mesh_agents:
-            # host-scale agent mesh: spend the leftover device factor on TP
-            mesh = make_host_mesh(
-                model=max(1, len(jax.devices()) // args.mesh_agents),
-                agents=args.mesh_agents)
-        else:
-            mesh = make_host_mesh(data=args.agents)
-        # registered (not assigned) so an in-process rerun with a different
-        # geometry replaces the entry loudly instead of leaking state
-        register_input_shape(shape, override=True)
-        shape_name = shape.name
+    shape = (INPUT_SHAPES[args.shape] if args.shape else
+             InputShape("custom", args.seq, args.global_batch, "train"))
+    devices = jax.devices()
+    if args.devices is not None:
+        if not 1 <= args.devices <= len(devices):
+            ap.error(f"--devices {args.devices}: {len(devices)} present")
+        devices = devices[:args.devices]
+    if args.mesh_agents:
+        mesh = make_host_mesh(model=max(1, len(devices) // K), agents=K,
+                              devices=devices)
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod,
-                                    agents=args.mesh_agents)
-        shape_name = args.shape
-        shape = INPUT_SHAPES[shape_name]
+        mesh = make_host_mesh(data=len(devices), devices=devices)
 
     ckpt_dir = (os.path.join(args.ckpt_dir, f"seed{args.seed}")
                 if args.ckpt_dir else None)
@@ -201,37 +223,42 @@ def main() -> None:
     run_log = RunLog(log_path, resume=resuming)
 
     with mesh:
-        bundle = S.build_train(cfg, mesh, shape_name,
+        bundle = S.build_train(cfg, mesh, shape,
                                combine_override=args.combine,
                                strategy=args.strategy,
                                schedule=args.topology_schedule,
                                link_failure_p=args.link_failure_p,
-                               schedule_seed=args.seed)
+                               schedule_seed=args.seed, agents=K)
         ucfg = bundle.mcfg.update_config
         sched = bundle.schedule
         print(f"[train] {cfg.name}: K={bundle.K} agents, "
               f"T={bundle.T} tasks × {bundle.tb} examples, "
-              f"mode={ucfg.inner}, seed={args.seed}")
+              f"mode={ucfg.inner}, seed={args.seed}, mesh "
+              f"{dict(zip(mesh.axis_names, mesh.devices.shape))} on "
+              f"{devices[0].platform}")
         if sched is not None:
             print(f"[train] outer update: strategy={ucfg.strategy} over "
                   f"'{sched.topology.name}' ({sched.kind} schedule, "
                   f"period {sched.period}, "
                   f"mean λ₂={sched.mean_mixing_rate:.3f}), "
                   f"combine_every={ucfg.combine_every}")
-        state = bundle.init_state(seed=args.seed)
+        # The state is created (or restored) straight into its steady-state
+        # shardings, and the step output is pinned to the same layout, so
+        # one compiled program serves every dispatch.  Built under jit, no
+        # device ever holds more than its own shards of the K-agent state.
         if resuming:
-            state = restore_checkpoint(ckpt_dir, state)
+            state = restore_checkpoint(ckpt_dir, bundle.state_specs,
+                                       shardings=bundle.state_shardings)
             print(f"[train] restored step {int(state.step)}")
+        else:
+            state = jax.jit(bundle.init_state,
+                            out_shardings=bundle.state_shardings)(args.seed)
         C = max(1, args.steps_per_dispatch)
-        # Commit the state to its steady-state shardings up front and pin
-        # the step output to the same layout: an uncommitted init state
-        # compiles the superstep once with unspecified input layouts, then
-        # the committed state it returns forces a second compile of the
-        # identical program — retrace-guard counts that as a cache miss.
-        state = jax.device_put(state, bundle.state_shardings)
         superstep_fn = jax.jit(S.make_superstep(bundle.step_fn),
                                donate_argnums=(0,),
                                out_shardings=(bundle.state_shardings, None))
+        executables = {}       # dispatch length -> compiled superstep
+        compile_s = 0.0
         source = make_train_source(cfg, shape, bundle.K, bundle.T, bundle.tb,
                                    seed=args.seed)
         print(f"[train] task source: {source.n_train_domains} domains "
@@ -265,6 +292,7 @@ def main() -> None:
         t0 = time.time()
         train_wall = 0.0       # train-compute only: excludes eval/ckpt/log
         done = 0
+        losses, disagreements, step_s = [], [], []
         with bundle.make_pipeline(source, depth=args.prefetch,
                                   start_step=int(state.step),
                                   stack=C) as pipe:
@@ -273,13 +301,22 @@ def main() -> None:
                 batch = next(pipe)
                 if n < C:      # final partial dispatch (one extra compile)
                     batch = {k: v[:n] for k, v in batch.items()}
+                if n not in executables:
+                    tc = time.perf_counter()
+                    executables[n] = superstep_fn.lower(state, batch).compile()
+                    compile_s += time.perf_counter() - tc
+                    print(f"[train] compiled the {n}-step dispatch in "
+                          f"{time.perf_counter() - tc:.1f}s")
                 td = time.perf_counter()
-                state, metrics = superstep_fn(state, batch)
+                state, metrics = executables[n](state, batch)
                 # ONE host sync per dispatch: the (n,)-shaped step-resolved
                 # metric arrays come back in a single fetch
                 m = jax.device_get(metrics)
                 dispatch_s = time.perf_counter() - td
                 train_wall += dispatch_s
+                losses += [float(x) for x in m["loss"]]
+                disagreements += [float(x) for x in m["disagreement"]]
+                step_s += [dispatch_s / n] * n
                 base, done = done, done + n
                 last_step = int(state.step)       # one fetch per dispatch
                 for j in range(n):
@@ -316,20 +353,19 @@ def main() -> None:
             save_checkpoint(ckpt_dir, int(state.step), state)
         # Post-run compiled-program lint (repro.analysis): retrace-guard
         # checks the traced step for weak-type python scalars and host
-        # callbacks, and asserts the superstep driver compiled exactly
-        # once per batch shape — 1, plus 1 more only when a final partial
-        # dispatch (steps % C != 0) forced a second shape.  The record
-        # lands in the run log for check_run_log.py --expect-analysis.
-        from repro.analysis.rules import CompileCounter, run_rules
+        # callbacks, and that the trainer compiled exactly one program per
+        # batch shape — 1, plus 1 more only when a final partial dispatch
+        # (steps % C != 0) forced a second shape.  The record lands in the
+        # run log for check_run_log.py --expect-analysis.
+        from repro.analysis.rules import run_rules
         from repro.analysis.run import context_for_bundle
         dispatches = -(-args.steps // C)
         expected_compiles = 1 + (1 if args.steps % C else 0)
-        compiles = CompileCounter(superstep_fn).count()
-        try:
-            jaxpr = jax.make_jaxpr(bundle.step_fn)(
-                bundle.state_specs, S.input_specs(cfg, shape_name))
-        except Exception:
-            jaxpr = None  # best-effort: compile counts still checked
+        compiles = len(executables)
+        kernels = sum(ex.as_text().count('custom_call_target="tpu_custom_call"')
+                      for ex in executables.values())
+        jaxpr = jax.make_jaxpr(bundle.step_fn)(
+            bundle.state_specs, S.input_specs(cfg, shape))
         ctx = context_for_bundle(
             bundle, jaxpr=jaxpr,
             compile_counts={"superstep": {"compiles": compiles,
@@ -339,12 +375,16 @@ def main() -> None:
         run_log.write(kind="analysis", **report.to_json(),
                       jit_compiles=compiles,
                       expected_compiles=expected_compiles,
-                      dispatches=dispatches)
+                      dispatches=dispatches, compile_s=round(compile_s, 3),
+                      tpu_custom_calls=kernels)
         if not report.ok:
             for f in report.findings:
                 print(f"[analysis] FINDING[{f.rule}] {f.message}")
     run_log.close()
     print(f"[train] done (run log: {log_path})")
+    return {"K": bundle.K, "loss": losses, "disagreement": disagreements,
+            "step_s": step_s, "compile_s": compile_s,
+            "tpu_custom_calls": kernels, "run_log": log_path}
 
 
 if __name__ == "__main__":

@@ -600,7 +600,10 @@ def ssd_scan(x, dt, A, B, C, chunk: int,
         dA = dtc * A                                            # (B,c,H) ≤ 0
         seg = jnp.cumsum(dA, axis=1)
         li = seg[:, :, None, :] - seg[:, None, :, :]            # (B,cq,ck,H)
-        decay = jnp.where(causal[None, :, :, None], jnp.exp(li), 0.0)
+        # mask before the exp: above the diagonal li > 0 grows with the
+        # chunk (exp overflows past ~88 at c=256), and where(mask, inf, 0)
+        # still sends inf·0 = NaN back through the exp in the backward
+        decay = jnp.exp(jnp.where(causal[None, :, :, None], li, -jnp.inf))
         cb = jnp.einsum("bqhn,bkhn->bqkh", Cc, Bc)
         M = (cb * decay * dtc[:, None, :, :]).astype(x.dtype)
         y = jnp.einsum("bqkh,bkhp->bqhp", M, xc)                # intra-chunk

@@ -63,7 +63,9 @@ _COND_HLO = textwrap.dedent("""
 
     %combine_branch (cp0.p: u16[1000]) -> u16[1000] {
       %cp0.p = u16[1000]{0} parameter(0)
-      %mix = f32[4,16]{1,0} dot(f32[4,4]{1,0} %A, f32[4,16]{1,0} %W), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+      %A = f32[4,4]{1,0} constant({...})
+      %W = f32[4,16]{1,0} constant({...})
+      %mix = f32[4,16]{1,0} dot(%A, %W), lhs_contracting_dims={1}, rhs_contracting_dims={0}
       %w0 = u16[1000]{0} collective-permute(%cp0.p), source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
       ROOT %w1 = u16[1000]{0} collective-permute(%w0), source_target_pairs={{0,3},{1,0},{2,1},{3,2}}
     }
@@ -179,7 +181,7 @@ def test_conditional_comm_flags_unconditional_mutation():
     broken = _COND_HLO.replace(
         "%epred = pred[] parameter(1)",
         "%epred = pred[] parameter(1)\n"
-        "  %hoist = f32[4,16]{1,0} dot(f32[4,4]{1,0} %A, f32[4,16]{1,0} %W)")
+        "  %hoist = f32[4,16]{1,0} dot(%A, %W)")
     rep = run_rules(_cond_ctx(broken), only=["conditional-comm"])
     assert not rep.ok
     assert any("unconditionally" in f.message for f in rep.findings)
@@ -401,7 +403,13 @@ def test_parse_computations_and_entry():
     comps, entry = H.parse_computations(_COND_HLO)
     assert entry == "main"
     assert set(comps) == {"main", "noop_branch", "combine_branch"}
-    assert len(comps["combine_branch"]) == 4
+    assert len(comps["combine_branch"]) == 6
+    # the printed HLO names operands without their types: rules resolve
+    # them through the module's definitions
+    shapes = H.instruction_shapes(comps)
+    assert shapes["A"] == "f32[4,4]" and shapes["cp0.p"] == "u16[1000]"
+    mix = next(l for l in comps["combine_branch"] if " dot(" in l)
+    assert H.operand_names(mix, "dot") == ["A", "W"]
 
 
 def test_reachable_stops_at_branches():

@@ -206,3 +206,94 @@ def test_opt_state_axes_match_structures():
     assert mom.velocity == p_axes
     ad = S.opt_state_axes("adam", p_axes)
     assert ad.mu == p_axes and ad.nu == p_axes and ad.step == ()
+
+
+def test_agent_count_keeps_callers_k():
+    """A caller's K is returned as given or refused with both numbers —
+    never replaced by what the mesh happens to hold."""
+    qw = get_config("qwen2-7b")          # placement=data: agents tile data
+    assert S.agent_count(qw, MESH1, agents=32) == 32   # 2 per data slice
+    with pytest.raises(ValueError, match=r"K=8 .*16 agent slice"):
+        S.agent_count(qw, MESH1, agents=8)
+    assert S.agent_count(qw, AMESH2, agents=16) == 16
+    with pytest.raises(ValueError, match=r"K=8 .*extent K"):
+        S.agent_count(qw, AMESH2, agents=8)
+
+
+# --- the trainer's entry point: K agents on whatever devices exist ----------
+
+# mamba2-130m (the chip smoke's reference arch) cut to its smoke widths
+TRAIN_ARGV = ["--arch", "mamba2-130m", "--reduced", "--seq", "32",
+              "--global-batch", "8", "--steps", "3", "--seed", "0"]
+
+MESH_AGENTS_SCRIPT = """
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, "src")
+from repro.launch import train
+out = train.main(json.loads(sys.argv[1]))
+print("RESULT " + json.dumps({k: out[k] for k in ("K", "loss",
+                                                  "disagreement")}))
+"""
+
+
+@pytest.fixture(scope="module")
+def stacked_run(tmp_path_factory):
+    from repro.launch import train
+    log = tmp_path_factory.mktemp("train") / "stacked.jsonl"
+    return train.main(TRAIN_ARGV + ["--agents", "4", "--run-log", str(log)])
+
+
+def test_train_main_stacks_k_agents_on_one_device(stacked_run):
+    """--agents 4 on the one CPU device runs K=4 (the agent copies stack on
+    the device) with the diffusion step live: disagreement > 0, falling."""
+    assert len(jax.devices()) == 1
+    assert stacked_run["K"] == 4
+    dis = stacked_run["disagreement"]
+    assert len(dis) == 3 and all(d > 0 for d in dis)
+    assert dis[2] < dis[1] < dis[0]
+    assert all(np.isfinite(stacked_run["loss"]))
+
+
+def test_mesh_agents_run_matches_stacked_run(stacked_run, tmp_path):
+    """One agent per (virtual) device with the ppermute combine on a bf16
+    wire follows the one-device stacked run of the same seed and data."""
+    import json
+    import os
+    import subprocess
+    import sys
+    argv = TRAIN_ARGV + ["--mesh-agents", "4",
+                         "--combine", "mesh_sparse_dynamic",
+                         "--run-log", str(tmp_path / "mesh.jsonl")]
+    proc = subprocess.run(
+        [sys.executable, "-c", MESH_AGENTS_SCRIPT, json.dumps(argv)],
+        capture_output=True, text=True, timeout=600,
+        cwd=os.path.join(os.path.dirname(__file__), ".."),
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")]
+    assert line, proc.stderr[-3000:]
+    mesh = json.loads(line[-1][len("RESULT "):])
+    assert mesh["K"] == 4
+    # the dense combine rounds the K×K weights to bf16, the ppermute
+    # combine keeps them f32: the runs agree to bf16 rounding, not bitwise
+    np.testing.assert_allclose(mesh["loss"], stacked_run["loss"], rtol=1e-3)
+    np.testing.assert_allclose(mesh["disagreement"],
+                               stacked_run["disagreement"], rtol=5e-2)
+
+
+@pytest.mark.parametrize("argv, match", [
+    (["--mesh-agents", "4"], r"agents=4 .* 1 available device"),
+    (["--agents", "4", "--mesh-agents", "2"], None),
+    (["--devices", "2"], None),
+])
+def test_train_main_rejects_geometry_that_does_not_factor(argv, match,
+                                                          tmp_path):
+    from repro.launch import train
+    argv = TRAIN_ARGV + argv + ["--run-log", str(tmp_path / "x.jsonl")]
+    if match is None:       # argparse refuses the request outright
+        with pytest.raises(SystemExit):
+            train.main(argv)
+    else:
+        with pytest.raises(ValueError, match=match):
+            train.main(argv)

@@ -143,6 +143,27 @@ def test_ssd_scan_chunk_invariance(chunk):
     np.testing.assert_allclose(s1, s2, atol=1e-4)
 
 
+def test_ssd_scan_grads_finite_at_published_chunk():
+    """mamba2-130m's chunk of 256 with its init decay (A = -1, dt ≈ 0.7):
+    above the diagonal the segment sums reach exp(+180), past f32's range.
+    The masked entries must not send inf·0 = NaN back through the exp."""
+    B, Lq, H, P, N = 1, 256, 2, 4, 8
+    ks = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(ks[0], (B, Lq, H, P))
+    dt = jnp.full((B, Lq, H), 0.7)
+    A = -jnp.ones((H,))
+    Bm = jax.random.normal(ks[1], (B, Lq, H, N)) * 0.5
+    Cm = jax.random.normal(ks[2], (B, Lq, H, N)) * 0.5
+
+    def loss(x, dt, A):
+        y, s = L.ssd_scan(x, dt, A, Bm, Cm, chunk=256)
+        return jnp.sum(y ** 2) + jnp.sum(s ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(x, dt, A)
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_mla_latent_dim_bottleneck():
     """MLA's KV path must flow through the rank-r latent."""
     cfg = get_config("deepseek-v2-lite-16b").reduced()

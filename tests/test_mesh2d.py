@@ -39,10 +39,11 @@ def test_make_host_mesh_agent_trivial_extent():
 
 
 def test_make_host_mesh_legacy_clamp_warns():
-    # the legacy path keeps its clamp semantics but reports both numbers
-    with pytest.warns(RuntimeWarning, match=r"data=4.*using.*data=1"):
-        mesh = make_host_mesh(data=4)
-    assert mesh.devices.shape == (1, 1)     # effective extents unchanged
+    # no clamp: a legacy request that does not factor raises with both
+    # numbers instead of shrinking the data extent under the caller
+    with pytest.raises(ValueError, match=r"data=4 .* 1 available device"):
+        make_host_mesh(data=4)
+    assert make_host_mesh(data=1).devices.shape == (1, 1)
 
 
 SCRIPT = textwrap.dedent("""
