@@ -10,6 +10,11 @@ Three modes:
   'fomaml'  first-order: curvature term dropped via stop_gradient on the
             inner gradient (Nichol et al. 2018; used for frontier-scale archs)
   'reptile' update direction = (w_adapted - w); no outer batch needed
+
+The phases carry ``jax.named_scope`` names — ``dif.step.inner_adapt``,
+``dif.step.outer_grad`` and ``dif.step.hvp`` — which reach the compiled
+module's op metadata only (no runtime cost), so a profile of the step can
+be split by them.
 """
 from __future__ import annotations
 
@@ -64,10 +69,11 @@ def inner_adapt(
     def one_step(p, _):
         return step_fn(p), None
 
-    if steps == 1:  # common case; keep the HLO flat
-        return step_fn(params)
-    adapted, _ = jax.lax.scan(one_step, params, None, length=steps)
-    return adapted
+    with jax.named_scope("dif.step.inner_adapt"):
+        if steps == 1:  # common case; keep the HLO flat
+            return step_fn(params)
+        adapted, _ = jax.lax.scan(one_step, params, None, length=steps)
+        return adapted
 
 
 def meta_loss(
@@ -123,7 +129,8 @@ def meta_grad(
         adapted = inner_adapt(loss_fn, params, support, alpha, steps, first_order=True)
         # Direction (w - w') / α plays the role of the meta-gradient.
         g = jax.tree.map(lambda p, a: (p - a) / max(alpha, 1e-12), params, adapted)
-        return loss_fn(adapted, query), g
+        with jax.named_scope("dif.step.outer_grad"):
+            return loss_fn(adapted, query), g
     if freeze_mask is not None:
         # ANIL-style partial adaptation (Raghu et al. 2020, cited by the
         # paper): frozen leaves are stop-gradiented inside the *inner* loss,
@@ -141,10 +148,12 @@ def meta_grad(
         grad_in = lambda p: jax.grad(inner_loss)(p, support)
         trajectory = []
         p = params
-        for _ in range(steps):
-            trajectory.append(p)
-            p = _sgd_step(p, grad_in(p), alpha)
-        loss, v = jax.value_and_grad(loss_fn)(p, query)
+        with jax.named_scope("dif.step.inner_adapt"):
+            for _ in range(steps):
+                trajectory.append(p)
+                p = _sgd_step(p, grad_in(p), alpha)
+        with jax.named_scope("dif.step.outer_grad"):
+            loss, v = jax.value_and_grad(loss_fn)(p, query)
         if hvp_subsample < 1.0:
             # beyond-paper knob: estimate ∇²Q_in on a support subsample.
             # The HVP is the most expensive pass of the meta step (measured
@@ -158,9 +167,10 @@ def meta_grad(
             grad_hvp = lambda p: jax.grad(inner_loss)(p, sub_batch)
         else:
             grad_hvp = grad_in
-        for w_j in reversed(trajectory):
-            _, hv = jax.jvp(grad_hvp, (w_j,), (v,))    # ∇²Q_in(w_j) · v
-            v = jax.tree.map(lambda a, b: a - alpha * b, v, hv)
+        with jax.named_scope("dif.step.hvp"):
+            for w_j in reversed(trajectory):
+                _, hv = jax.jvp(grad_hvp, (w_j,), (v,))    # ∇²Q_in(w_j) · v
+                v = jax.tree.map(lambda a, b: a - alpha * b, v, hv)
         return loss, v
     # fomaml / maml_naive: adapt with the (possibly masked) inner loss, take
     # the outer loss unmasked so frozen leaves still receive meta-gradients
@@ -169,7 +179,8 @@ def meta_grad(
     def full(p):
         adapted = inner_adapt(inner_loss, p, support, alpha, steps,
                               first_order=first_order)
-        return loss_fn(adapted, query)
+        with jax.named_scope("dif.step.outer_grad"):
+            return loss_fn(adapted, query)
 
     return jax.value_and_grad(full)(params)
 

@@ -293,6 +293,9 @@ def make_meta_step(
             backend = "sparse_host"
         backend = diffusion.resolve_schedule_backend(backend, A)
         combine_fn = diffusion.make_combine(backend, A=A)
+    if combine_fn is not None:
+        # one name for the diffusion combine, whichever backend made it
+        combine_fn = jax.named_scope("dif.step.combine")(combine_fn)
 
     def per_agent(params_k, support_k, query_k):
         return maml.multi_task_meta_grad(
@@ -312,22 +315,24 @@ def make_meta_step(
                                  base)
                     if gated else mix(base))
         losses, grads = jax.vmap(per_agent)(base, support, query)
-        if fused_outer is not None:
-            # no lax.cond: skipped comm steps must still advance the
-            # moments, and the kernel's gate blends the mix to identity
-            params, opt_state = fused_outer(base, grads, state.opt_state,
-                                            idx)
-        else:
-            if cfg.grad_clip is not None:   # 0.0 is a valid (total) clip
-                grads = jax.vmap(lambda g: clip_by_global_norm(g, cfg.grad_clip))(grads)
-            updates, opt_state = opt.update(grads, state.opt_state, base)
-            if gated and not strategy.pre_combine:
-                params = jax.lax.cond(
-                    comm.is_comm_step(idx),
-                    lambda p, u: strategy.apply(p, u, combine_fn, idx),
-                    update.local_update, base, updates)
+        with jax.named_scope("dif.step.outer_update"):
+            if fused_outer is not None:
+                # no lax.cond: skipped comm steps must still advance the
+                # moments, and the kernel's gate blends the mix to identity
+                params, opt_state = fused_outer(base, grads, state.opt_state,
+                                                idx)
             else:
-                params = strategy.apply(base, updates, combine_fn, idx)
+                if cfg.grad_clip is not None:   # 0.0 is a valid (total) clip
+                    grads = jax.vmap(lambda g: clip_by_global_norm(
+                        g, cfg.grad_clip))(grads)
+                updates, opt_state = opt.update(grads, state.opt_state, base)
+                if gated and not strategy.pre_combine:
+                    params = jax.lax.cond(
+                        comm.is_comm_step(idx),
+                        lambda p, u: strategy.apply(p, u, combine_fn, idx),
+                        update.local_update, base, updates)
+                else:
+                    params = strategy.apply(base, updates, combine_fn, idx)
         metrics = {
             "loss": jnp.mean(losses),
             "per_agent_loss": losses,

@@ -171,7 +171,9 @@ def _none(params, updates, combine_fn, step):
 def _centralized(params, updates, combine_fn, step):
     """Every agent receives the centroid of the adapted iterates — the
     paper's centralized reference (A = (1/K)·11ᵀ, graph-independent)."""
-    return diffusion.centralized_combine(local_update(params, updates))
+    adapted = local_update(params, updates)
+    with jax.named_scope("dif.step.combine"):
+        return diffusion.centralized_combine(adapted)
 
 
 # ---------------------------------------------------------------------------

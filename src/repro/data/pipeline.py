@@ -19,6 +19,8 @@ import queue
 import threading
 from typing import Any, Callable
 
+import jax
+
 from repro.data.episodes import Episode, TaskSource
 
 __all__ = ["MetaBatchPipeline"]
@@ -68,11 +70,13 @@ class MetaBatchPipeline:
 
     def _sample_item(self, step: int) -> Any:
         """One prepared item: a single episode, or ``stack`` consecutive
-        episodes handed to ``prepare`` as a list."""
-        if self.stack == 1:
-            return self._prepare(self.source.sample(step))
-        return self._prepare([self.source.sample(step + j)
-                              for j in range(self.stack)])
+        episodes handed to ``prepare`` as a list; a profiler host span
+        ``dif.pipeline.produce`` on the producing thread."""
+        with jax.profiler.TraceAnnotation("dif.pipeline.produce"):
+            if self.stack == 1:
+                return self._prepare(self.source.sample(step))
+            return self._prepare([self.source.sample(step + j)
+                                  for j in range(self.stack)])
 
     def _worker(self) -> None:
         step = self._step

@@ -68,24 +68,29 @@ def block_specs(cfg: ArchConfig, desc: BlockDesc) -> PyTree:
 
 def block_apply(cfg: ArchConfig, desc: BlockDesc, p: PyTree, x: jax.Array,
                 positions: jax.Array, aux: dict[str, jax.Array]) -> jax.Array:
-    h = L.norm_apply(p["norm1"], x)
-    if desc.mixer == "attn":
-        x = x + L.attention_apply(p["attn"], cfg, h, positions, causal=True)
-    elif desc.mixer == "attn_nc":
-        x = x + L.attention_apply(p["attn"], cfg, h, positions, causal=False)
-    elif desc.mixer == "mla":
-        x = x + L.mla_apply(p["mla"], cfg, h, positions)
-    elif desc.mixer == "mamba":
-        x = x + L.mamba2_apply(p["mamba"], cfg, h)
-    elif desc.mixer == "cross":
-        y = L.attention_apply(p["cross"], cfg, h, positions, causal=False,
-                              kv_x=aux["enc"])
-        x = x + jnp.tanh(p["gate"]) * y
+    # each sublayer (norm, op, residual) under one name, for profiles
+    with jax.named_scope("dif.model.mixer"):
+        h = L.norm_apply(p["norm1"], x)
+        if desc.mixer == "attn":
+            x = x + L.attention_apply(p["attn"], cfg, h, positions,
+                                      causal=True)
+        elif desc.mixer == "attn_nc":
+            x = x + L.attention_apply(p["attn"], cfg, h, positions,
+                                      causal=False)
+        elif desc.mixer == "mla":
+            x = x + L.mla_apply(p["mla"], cfg, h, positions)
+        elif desc.mixer == "mamba":
+            x = x + L.mamba2_apply(p["mamba"], cfg, h)
+        elif desc.mixer == "cross":
+            y = L.attention_apply(p["cross"], cfg, h, positions, causal=False,
+                                  kv_x=aux["enc"])
+            x = x + jnp.tanh(p["gate"]) * y
     if desc.ffn != "none":
-        h = L.norm_apply(p["norm2"], x)
-        out = (L.moe_apply(p["ffn"], cfg, h) if desc.ffn == "moe"
-               else L.mlp_apply(p["ffn"], h))
-        x = x + out
+        with jax.named_scope("dif.model.ffn"):
+            h = L.norm_apply(p["norm2"], x)
+            out = (L.moe_apply(p["ffn"], cfg, h) if desc.ffn == "moe"
+                   else L.mlp_apply(p["ffn"], h))
+            x = x + out
     return x
 
 
@@ -330,15 +335,19 @@ class Model:
         for seg, sp in zip(self.plan, params["segments"]):
             x = segment_apply(cfg, seg, sp, x, positions, aux,
                               constrain=self._constrain)
-        x = L.norm_apply(params["final_norm"], x)
-        return x @ params["head"]
+        with jax.named_scope("dif.model.head"):
+            x = L.norm_apply(params["final_norm"], x)
+            return x @ params["head"]
 
     def loss_fn(self, params: PyTree, batch: dict) -> jax.Array:
-        logits = self.forward(params, batch).astype(jnp.float32)
-        labels = batch["labels"]
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
+        logits = self.forward(params, batch)
+        with jax.named_scope("dif.model.head"):
+            logits = logits.astype(jnp.float32)
+            labels = batch["labels"]
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            return jnp.mean(logz - gold)
 
     # -- decode --------------------------------------------------------------
     def cache_specs(self, batch: int, cache_len: int) -> PyTree:
