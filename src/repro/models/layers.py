@@ -90,12 +90,95 @@ def _sdpa(q, k, v, mask, scale):
     return jnp.einsum("bhst,bthd->bshd", probs, v)
 
 
+def causal_key_ranges(S: int, q_chunk: int) -> list[tuple[int, int]]:
+    """The key range ``[lo, hi)`` that each query chunk of causal
+    self-attention over S positions can see.
+
+    Chunk ``i`` holds queries ``i*q_chunk .. (i+1)*q_chunk - 1``; no row of
+    it sees a key past its last row.  ``sum(hi - lo) / (S // q_chunk * S)``
+    is the share of the logit tiles that :func:`_sdpa_causal_folded`
+    computes; a sliding window is left to its mask."""
+    return [(0, (i + 1) * q_chunk) for i in range(S // q_chunk)]
+
+
+def _sdpa_causal_folded(q, k, v, scale, *, window: int | None, q_chunk: int):
+    """Causal self-attention that computes only the key blocks each query
+    chunk can see (:func:`causal_key_ranges`), in one ``lax.scan``.
+
+    The ranges grow chunk by chunk, so the scan folds them: step ``a`` takes
+    chunk ``a`` with chunk ``b = nc-1-a``, whose ranges hold ``nc + 1`` key
+    blocks together at every step.  Both are read from one window of the
+    keys laid out as ``S-1 .. 0, 0 .. S-1``: chunk ``a``'s range reversed,
+    then chunk ``b``'s.  Each block of the window meets the query chunk it
+    belongs to, and each chunk's softmax runs over its own blocks.  With an
+    odd count the middle chunk is paired with itself, and one copy is
+    dropped.  The f32 softmax, its mask and ``NEG_INF`` are those of the
+    unfolded path; each step's backward recomputes its logit tiles."""
+    B, S, H, D = q.shape
+    c = q_chunk
+    ranges = causal_key_ranges(S, c)
+    nc = len(ranges)
+    steps = (nc + 1) // 2
+    nb = (ranges[0][1] + ranges[-1][1]) // c          # key blocks a step: nc + 1
+    kk = jnp.concatenate([jnp.flip(k, 1), k], axis=1)
+    vv = jnp.concatenate([jnp.flip(v, 1), v], axis=1)
+    qb = q.reshape(B, nc, c, H, D)
+    blk = jnp.arange(nb)
+    u = blk[:, None] * c + jnp.arange(c)[None, :]     # (nb, c) window slot
+
+    @jax.checkpoint
+    def fold_attn(qa, qb_, a, kk, vv):
+        hi_a = (a + 1) * c                            # chunk a's keys: [0, hi_a)
+        kw = jax.lax.dynamic_slice_in_dim(kk, S - hi_a, nb * c, axis=1)
+        vw = jax.lax.dynamic_slice_in_dim(vv, S - hi_a, nb * c, axis=1)
+        seg = blk <= a                                # the block belongs to chunk a
+        qt = jnp.where(seg[None, :, None, None, None], qa[:, None], qb_[:, None])
+        kpos = jnp.where(seg[:, None], hi_a - 1 - u, u - hi_a)
+        qpos = (jnp.where(seg, a, nc - 1 - a)[:, None] * c
+                + jnp.arange(c)[None, :])
+        mask = kpos[:, None, :] <= qpos[:, :, None]   # (nb, c queries, c keys)
+        if window is not None:
+            mask = mask & (kpos[:, None, :] > qpos[:, :, None] - window)
+        logits = jnp.einsum("btqhd,btkhd->bthqk", qt,
+                            kw.reshape(B, nb, c, H, D)).astype(jnp.float32)
+        logits = jnp.where(mask[None, :, None], logits * scale, NEG_INF)
+
+        def per_chunk(x, reduce, fill):
+            # reduce over chunk a's blocks, then over chunk b's
+            in_a = seg.reshape((1, nb) + (1,) * (x.ndim - 2))
+            return (reduce(jnp.where(in_a, x, fill), 1, keepdims=True),
+                    reduce(jnp.where(in_a, fill, x), 1, keepdims=True))
+
+        in_a = seg[None, :, None, None]
+        ma, mb = per_chunk(jnp.max(logits, -1), jnp.max, -jnp.inf)
+        m = jax.lax.stop_gradient(jnp.where(in_a, ma, mb))
+        e = jnp.exp(logits - m[..., None])
+        la, lb = per_chunk(jnp.sum(e, -1), jnp.sum, 0.0)
+        probs = (e / jnp.where(in_a, la, lb)[..., None]).astype(v.dtype)
+        out = jnp.einsum("bthqk,btkhd->btqhd", probs,
+                         vw.reshape(B, nb, c, H, v.shape[-1]),
+                         preferred_element_type=jnp.float32)
+        oa, ob = per_chunk(out, jnp.sum, 0.0)
+        return oa[:, 0].astype(v.dtype), ob[:, 0].astype(v.dtype)
+
+    def body(_, inp):
+        qa, qb_, a = inp
+        return None, fold_attn(qa, qb_, a, kk, vv)
+
+    xs = (jnp.moveaxis(qb[:, :steps], 1, 0),
+          jnp.moveaxis(qb[:, ::-1][:, :steps], 1, 0), jnp.arange(steps))
+    _, (out_a, out_b) = jax.lax.scan(body, None, xs)
+    out = jnp.concatenate([out_a, out_b[::-1][nc % 2:]], axis=0)   # (nc,B,c,H,D)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, v.shape[-1])
+
+
 def _sdpa_chunked(q, k, v, scale, *, causal: bool, window: int | None,
                   q_chunk: int):
-    """Query-chunked attention: the (S, T) logits tensor is never
-    materialized — only (q_chunk, T) tiles inside a lax.scan.  This is the
-    jnp analogue of the Pallas flash kernel (kernels/flash_attention) and
-    keeps the HBM roofline term O(S·d) instead of O(S²)."""
+    """Query-chunked attention over all T keys, for non-causal attention
+    and cross-lengths: one (q_chunk, T) logit tile at a time inside a
+    lax.scan, so the (S, T) square is never live at once.  The tiles still
+    go through HBM, unlike those of the Pallas flash kernel
+    (kernels/flash_attention), which stay on chip."""
     B, S, H, D = q.shape
     T = k.shape[1]
     nc = S // q_chunk
@@ -125,10 +208,14 @@ def _sdpa_chunked(q, k, v, scale, *, causal: bool, window: int | None,
 
 def sdpa(q, k, v, scale, *, causal: bool, window: int | None = None,
          q_chunk: int | None = 512):
-    """Dispatch: chunked when the query length divides cleanly, full
-    otherwise (short sequences / encoder lengths like 1500)."""
+    """Dispatch: chunked when the query length divides cleanly (causal
+    self-attention folded to the visible key blocks), full otherwise
+    (short sequences / encoder lengths like 1500)."""
     S, T = q.shape[1], k.shape[1]
     if q_chunk and S > q_chunk and S % q_chunk == 0:
+        if causal and T == S:
+            return _sdpa_causal_folded(q, k, v, scale, window=window,
+                                       q_chunk=q_chunk)
         return _sdpa_chunked(q, k, v, scale, causal=causal, window=window,
                              q_chunk=q_chunk)
     qpos = jnp.arange(S)[:, None]

@@ -106,13 +106,59 @@ def test_gqa_expand_matches_mha_when_equal_heads():
     np.testing.assert_array_equal(ke[:, :, 0], ke[:, :, 2])
 
 
-@pytest.mark.parametrize("q_chunk", [4, 8, None])
-def test_sdpa_chunk_invariance(q_chunk):
-    q, k, v = [jax.random.normal(jax.random.key(i), (2, 16, 3, 8))
-               for i in range(3)]
-    full = L.sdpa(q, k, v, 0.35, causal=True, q_chunk=None)
-    out = L.sdpa(q, k, v, 0.35, causal=True, q_chunk=q_chunk)
+@pytest.mark.parametrize("q_chunk,causal,window,S,dv", [
+    pytest.param(4, True, None, 16, 8, id="4"),        # 4 chunks
+    pytest.param(8, True, None, 16, 8, id="8"),        # 2 chunks
+    pytest.param(None, True, None, 16, 8, id="None"),
+    pytest.param(4, True, None, 12, 8, id="4-odd-chunks"),
+    pytest.param(4, True, 3, 16, 8, id="4-window-below-chunk"),
+    pytest.param(4, True, 4, 16, 8, id="4-window-at-chunk"),
+    pytest.param(4, True, 6, 16, 8, id="4-window-above-chunk"),
+    pytest.param(8, True, 5, 16, 8, id="8-window-below-chunk"),
+    pytest.param(8, True, 11, 16, 8, id="8-window-above-chunk"),
+    pytest.param(4, True, 5, 20, 8, id="4-window-odd-chunks"),
+    pytest.param(4, False, None, 16, 8, id="4-noncausal"),
+    pytest.param(8, False, None, 16, 8, id="8-noncausal"),
+    pytest.param(4, True, None, 16, 5, id="4-value-width-differs"),   # MLA
+])
+def test_sdpa_chunk_invariance(q_chunk, causal, window, S, dv):
+    q, k, v = [jax.random.normal(jax.random.key(i), (2, S, 3, d))
+               for i, d in enumerate((8, 8, dv))]
+    full = L.sdpa(q, k, v, 0.35, causal=causal, window=window, q_chunk=None)
+    out = L.sdpa(q, k, v, 0.35, causal=causal, window=window,
+                 q_chunk=q_chunk)
     np.testing.assert_allclose(out, full, atol=1e-5)
+
+
+@pytest.mark.parametrize("window,S", [(None, 16), (6, 16), (None, 12)])
+def test_sdpa_chunked_hvp_matches_unchunked(window, S):
+    """``jax.jvp(jax.grad(loss))``, the product ``maml`` takes, through the
+    chunked causal path equals the same product through the full square."""
+    qkv = tuple(jax.random.normal(jax.random.key(i), (2, S, 3, 8))
+                for i in range(3))
+    tangent = tuple(jax.random.normal(jax.random.key(10 + i), (2, S, 3, 8))
+                    for i in range(3))
+
+    def hvp(q_chunk):
+        def loss(args):
+            out = L.sdpa(*args, 0.35, causal=True, window=window,
+                         q_chunk=q_chunk)
+            return jnp.sum(jnp.sin(out))
+        return jax.jvp(jax.grad(loss), (qkv,), (tangent,))
+
+    (g_c, hv_c), (g_f, hv_f) = hvp(4), hvp(None)
+    for a, b in zip(g_c + hv_c, g_f + hv_f):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_causal_key_ranges():
+    assert L.causal_key_ranges(16, 4) == [(0, 4), (0, 8), (0, 12), (0, 16)]
+    # the qwen2 benchmark cell: S = 4096 in chunks of 512
+    ranges = L.causal_key_ranges(4096, 512)
+    assert len(ranges) == 8 and ranges[-1] == (0, 4096)
+    assert sum(hi - lo for lo, hi in ranges) // 512 == 36     # of 8 x 8 tiles
+    # the fold pairs chunk i with chunk 7 - i: nc + 1 key blocks a step
+    assert {ranges[i][1] + ranges[7 - i][1] for i in range(4)} == {9 * 512}
 
 
 # ---------------------------------------------------------------------------
