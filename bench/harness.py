@@ -4,8 +4,19 @@ The cell (a ``workloads`` entry of ``BENCHMARK.json``) names a
 configuration, ``bench/configs/<config>.json``, and a traffic mix,
 ``bench/traffic/<traffic>.json``; its limits are ``bench/limits/<cell>.json``
 and each metric is read by ``bench/metrics/<metric>.py``.  Nothing here
-names a cell, a configuration or a metric, so a later change adds one with
-files alone.
+names a cell, a configuration, a model family or a metric, so a later
+change adds one with files alone:
+
+* a configuration file holds ``arch``, the keyword arguments of the
+  program's ``ArchConfig`` (a key left out takes its default there),
+  ``base``, the repository's configuration it is cut from, ``reduced``, the
+  keys of the model's shape changed from that, and ``job``, the keys of
+  ``ArchConfig``'s meta-learning block that the cell sets apart from it;
+  ``reference`` names its model family;
+* a model family is one file, ``bench/reference/<family>.py``: its plain
+  reference ``loss``, its ``forward_flops_per_token(arch, seq)`` by the
+  conventions of ``bench/flops.py``, and, where its weights need one, a
+  ``fan_in(name, core_shape)`` rule for ``bench/weights.py``.
 
 A run (:func:`run_cell`):
 
@@ -20,8 +31,10 @@ A run (:func:`run_cell`):
 4. measures a window of back-to-back steps, with no host sync but the last;
    with ``--trace 1`` the profiler records the window's last second or so
    of steps, from a drained queue, as the host span ``bench.window``;
-5. after the window, reads the peak memory, frees the program's state, and
-   runs the plain reference over the same weights and batches.
+5. after the window, reads the peak memory, frees the program's state,
+   with ``--trace 1`` splits the traced steps' device time by the
+   program's named scopes (``bench/scopes.py``), and runs the plain
+   reference over the same weights and batches.
 """
 from __future__ import annotations
 
@@ -124,6 +137,11 @@ class Run:
     chips: int
     peaks: dict | None
     trace: object = None              # trace.Summary with --trace 1
+    # With --trace 1: device ms a step by (step scope, model scope), mean
+    # over the chips, and the producer thread's ms a step
+    # (bench/scopes.py; None where no such span ran).
+    scope_ms: dict | None = None
+    produce_ms: float | None = None
 
 
 class CompileClock:
@@ -176,6 +194,7 @@ class _Source:
 class Program:
     """The compiled meta step with its bundle, traffic and state maker."""
     cell: Cell
+    arch: dict                   # ArchConfig's fields, defaults filled in
     bundle: object
     mesh: object
     step: object                 # compiled (state, batch) -> (state, metrics)
@@ -194,6 +213,7 @@ def build_program(cell: Cell, devices) -> Program:
 
     ArchConfig, InputShape, Episode, steps, _, make_host_mesh = _program()
     cfg = ArchConfig(**cell.config["arch"])
+    family = cell.config["reference"]
     tr = cell.traffic
     K = int(tr["agents"])
     devices = list(devices)[:cell.chips]
@@ -212,11 +232,13 @@ def build_program(cell: Cell, devices) -> Program:
         zeros = lambda t: jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), t)
         return specs._replace(step=jnp.zeros((), jnp.int32),
-                              params=make_params(specs.params, key_data),
+                              params=make_params(specs.params, key_data,
+                                                 family=family),
                               opt_state=zeros(specs.opt_state))
 
     make_state = jax.jit(state_from, out_shardings=bundle.state_shardings)
-    params0 = jax.jit(lambda k: make_params(specs.params, k),
+    params0 = jax.jit(lambda k: make_params(specs.params, k,
+                                            family=family),
                       out_shardings=bundle.state_shardings.params)
     jitted = jax.jit(bundle.step_fn, donate_argnums=(0,),
                      out_shardings=(bundle.state_shardings, None))
@@ -229,8 +251,8 @@ def build_program(cell: Cell, devices) -> Program:
     t = time.perf_counter()
     with mesh:
         compiled = jitted.lower(state_in, batch_in).compile()
-    return Program(cell, bundle, mesh, compiled, make_state, params0, Episode,
-                   time.perf_counter() - t)
+    return Program(cell, dataclasses.asdict(cfg), bundle, mesh, compiled,
+                   make_state, params0, Episode, time.perf_counter() - t)
 
 
 def traffic_for(prog: Program, seed: int):
@@ -303,7 +325,7 @@ def reference_readings(prog: Program, traffic, seed: int,
     from bench import reference
     from bench.weights import seed_data
 
-    arch = prog.cell.config["arch"]
+    arch = prog.arch
     if arch["topology"] != "ring" or arch["meta_mode"] != "maml" \
             or arch["inner_steps"] != 1 or arch["outer_optimizer"] != "adam":
         raise ValueError("the reference runs ring/maml/1 inner step/adam")
@@ -379,7 +401,7 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
     """One run; returns the result line's object, ``checks`` last."""
     import jax
 
-    from bench import flops
+    from bench import flops, scopes
     from bench import trace as tracing
 
     enable_cache = _program()[4]
@@ -425,17 +447,25 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
     failed = int(sum(not math.isfinite(float(x))
                      for x in jax.device_get(losses)))
     del state, losses
-    summary = None
+    summary = scoped = None
     if trace:
-        summary = tracing.summarize(tracing.load(tracing.find_xplane(tdir)))
+        path = tracing.find_xplane(tdir)
+        loaded, spans = tracing.load(path), scopes.program_spans(path)
         shutil.rmtree(tdir, ignore_errors=True)
+        summary = tracing.summarize(loaded)
+        t_scopes = time.perf_counter()
+        scoped = scopes.per_step(loaded, spans, prog.step.as_text(), traced)
+        scoped["notes"]["scopes_s"] = time.perf_counter() - t_scopes
+        del loaded, spans
     run = Run(setup_s=setup_s, window_s=window_s, steps=n,
               traced_steps=traced,
               tokens_per_step=tr.tokens_per_step,
               step_flops=flops.meta_step_flops(
-                  cell.config["arch"], K=tr.K, T=tr.T, tb=tr.tb,
-                  seq=tr.seq_len),
-              chips=cell.chips, peaks=peaks, trace=summary)
+                  cell.config["reference"], prog.arch, K=tr.K, T=tr.T,
+                  tb=tr.tb, seq=tr.seq_len),
+              chips=cell.chips, peaks=peaks, trace=summary,
+              scope_ms=scoped["scope_ms"] if scoped else None,
+              produce_ms=scoped["produce_ms"] if scoped else None)
     metrics_out = {}
     for m in cell.metrics:
         value = load_reader(m["name"], cell.root)(run)
@@ -464,5 +494,7 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
                     "traced_steps": traced,
                     "window_s": window_s, "first_step_estimate_s": step_s,
                     "reference_s": reference_s}
+    if scoped is not None:
+        out["notes"].update(scoped["notes"])
     out["checks"] = checks
     return out

@@ -44,10 +44,23 @@ from .ops import Ops, fp8_round
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
 
-def model_loss(family: str):
-    """``loss(ops, params, tokens, labels, cfg)`` of a model family, from
-    the module of that name beside this file."""
-    return importlib.import_module(f"{__name__}.{family}").loss
+def family(name: str):
+    """The module of the model family ``name``: ``<name>.py`` beside this
+    file.  It gives ``loss`` and ``forward_flops_per_token``, and may give
+    ``fan_in`` (``bench/weights.py``).  An unknown family is an error."""
+    module = f"{__name__}.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(f"no model family {name!r} in bench/reference/"
+                         ) from None
+
+
+def model_loss(name: str):
+    """``loss(ops, params, tokens, labels, cfg)`` of a model family."""
+    return family(name).loss
 
 
 def ring_metropolis(K: int) -> np.ndarray:

@@ -93,3 +93,25 @@ def loss(ops: Ops, params: dict, tokens: jax.Array, labels: jax.Array,
         return h + _mixer(ops, p["mamba"], rms_norm(h, p["norm1"]["scale"]))
 
     return lm_loss(ops, scan_layers(block, x, layers), params, labels)
+
+
+def forward_flops_per_token(arch: dict, seq: int) -> float:
+    """Forward FLOPs a token of a sequence of length ``seq``, by the
+    conventions of ``bench/flops.py``:
+
+    * every projection and the head: 2 x (weights of the product);
+    * the depthwise convolution: 2 x width x channels;
+    * the SSD layer in its chunked form (chunk c = min(ssm_chunk, seq)),
+      per head: (c + 1)(N + P) for C.B and the masked product with x inside
+      the chunk (each a causal triangle), and 4NP for reading and writing
+      the state carried between chunks.
+    """
+    d, V, L = arch["d_model"], arch["vocab_size"], arch["num_layers"]
+    H = arch["ssm_expand"] * d // arch["ssm_head_dim"]
+    P, N, G = arch["ssm_head_dim"], arch["ssm_state"], arch["ssm_groups"]
+    c = min(arch["ssm_chunk"], seq)
+    proj = 2 * d * H * P + 2 * d * G * N + d * H + H * P * d
+    conv = arch["ssm_conv"] * (H * P + 2 * G * N)
+    ssd = H * ((c + 1) * (N + P) + 4 * N * P)
+    layer = 2 * proj + 2 * conv + ssd
+    return float(L * layer + 2 * d * V)

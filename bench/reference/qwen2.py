@@ -76,3 +76,15 @@ def loss(ops: Ops, params: dict, tokens: jax.Array, labels: jax.Array,
         return h + _mlp(ops, p["ffn"], rms_norm(h, p["norm2"]["scale"]))
 
     return lm_loss(ops, scan_layers(block, x, layers), params, labels)
+
+
+def forward_flops_per_token(arch: dict, seq: int) -> float:
+    """Forward FLOPs a token of a sequence of length ``seq``, by the
+    conventions of ``bench/flops.py``: every projection, the MLP and the
+    head 2 x (weights of the product); causal attention 2 (seq + 1)
+    head_dim heads for q.k and probs.v."""
+    d, V, L = arch["d_model"], arch["vocab_size"], arch["num_layers"]
+    H, KV, hd = arch["num_heads"], arch["num_kv_heads"], arch["head_dim"]
+    proj = d * H * hd * 2 + d * KV * hd * 2 + 3 * d * arch["d_ff"]
+    layer = 2 * proj + 2 * (seq + 1) * hd * H
+    return float(L * layer + 2 * d * V)

@@ -22,18 +22,22 @@ compiled step's HLO text:
   a family counts as ``unscoped`` there, so each family's times add up to
   the device's busy time;
 * :func:`program_spans`: the host events named ``dif.*``, on the trace's
-  clock.
+  clock;
+* :func:`per_step`: what a ``--trace 1`` run of the harness puts on its
+  ``Run`` for the metric readers (``bench/metrics/<scope>_ms.py``, each
+  through :func:`ms_under`), and its notes.
 
 Run as a script, it runs one cell as ``bench/run.py --trace 1`` does, whose
 result line it prints first, and then one more line: the traced steps'
-device time by phase and by block, in ms a step.
+device time by phase, by block and by the pair of them, in ms a step, with
+the coverage of the ops' own ``op_name`` paths beside the inferred one.
 
   python3 bench/scopes.py --workload <cell> --seed <n> --seconds <s>
 
-The harness keeps neither the trace nor the compiled step's text for its
-metric readers, so the script wraps ``harness.build_program``,
-``harness.run_cell`` and ``trace.load`` to keep them; the run itself is the
-harness's, unchanged.
+The harness keeps neither the trace nor the compiled step's text past its
+run, so the script wraps ``harness.build_program``, ``harness.run_cell``
+and ``trace.load`` to keep them; the run itself is the harness's,
+unchanged.
 """
 from __future__ import annotations
 
@@ -205,6 +209,41 @@ def span_time(spans: list, name: str, lo: int, hi: int) -> tuple:
     inside = [(max(s, lo), min(e, hi)) for n, s, e in spans
               if n == name and e > lo and s < hi]
     return sum(e - s for s, e in inside) * 1e-9, len(inside)
+
+
+def per_step(trace, spans: list, hlo_text: str, steps: int) -> dict:
+    """A traced run's readings over its ``steps`` traced steps, from the
+    trace (``bench.trace.load``), the program's host spans
+    (:func:`program_spans`) and the compiled step's HLO text:
+
+    * ``scope_ms``: device ms a step by (step scope, model scope), mean over
+      the chips (:func:`scope_times`);
+    * ``produce_ms``: the producer thread's ms a step, ``None`` where no
+      such span ran in the window;
+    * ``notes``: each family's unscoped ms a step and the coverages.
+    """
+    from bench import trace as tracing
+    times = scope_times(trace, op_scopes(hlo_text))
+    window = next((s, e) for n, s, e in trace.spans
+                  if n == tracing.WINDOW_SPAN)
+    produce_s, produce_n = span_time(spans, PRODUCE_SPAN, *window)
+    fam = by_family(times)
+    ms = lambda t: 1e3 * t / steps
+    return {"scope_ms": {k: ms(t) for k, t in times.items()},
+            "produce_ms": ms(produce_s) if produce_n else None,
+            "notes": {"unscoped_ms": {f: ms(fam[f].get(UNSCOPED, 0.0))
+                                      for f in FAMILIES},
+                      "step_coverage": step_coverage(times),
+                      "model_coverage": model_coverage(times)}}
+
+
+def ms_under(run, family: str, scope: str) -> float | None:
+    """A run's device ms a step under ``scope`` of ``family`` (each of the
+    family's scopes summed over the other family's), ``None`` where the
+    run read no scopes or none of that name."""
+    if run.scope_ms is None:
+        return None
+    return by_family(run.scope_ms)[family].get(scope)
 
 
 def step_coverage(times: dict) -> float | None:

@@ -134,14 +134,14 @@ def test_peaks_table():
         harness.load_peaks("cpu")
 
 
-@pytest.mark.parametrize("base,kw", [
-    ("mamba2-130m", dict(num_layers=1, d_model=256, vocab_size=1024,
-                         ssm_chunk=256)),
-    ("qwen2-1.5b", dict(num_layers=1, d_model=256, num_heads=4,
-                        num_kv_heads=2, head_dim=64, d_ff=1024,
-                        vocab_size=1024, attn_q_chunk=None)),
+@pytest.mark.parametrize("base,family,kw", [
+    ("mamba2-130m", "mamba2", dict(num_layers=1, d_model=256,
+                                   vocab_size=1024, ssm_chunk=256)),
+    ("qwen2-1.5b", "qwen2", dict(num_layers=1, d_model=256, num_heads=4,
+                                 num_kv_heads=2, head_dim=64, d_ff=1024,
+                                 vocab_size=1024, attn_q_chunk=None)),
 ])
-def test_forward_flops_agree_with_xla(base, kw):
+def test_forward_flops_agree_with_xla(base, family, kw):
     """One forward pass at a small width, one layer and one chunk, so that
     XLA's count has no loop body counted once.  XLA computes the masked
     upper triangle of the causal products, which the count leaves out, so
@@ -163,7 +163,7 @@ def test_forward_flops_agree_with_xla(base, kw):
              for k in ("tokens", "labels")}
     xla = dict(jax.jit(model.forward).lower(params, batch).compile()
                .cost_analysis())["flops"]
-    ours = R * S * flops.forward_flops_per_token(arch, S)
+    ours = R * S * flops.forward_flops_per_token(family, arch, S)
     if cfg.arch_type == "ssm":
         H = cfg.ssm_d_inner // cfg.ssm_head_dim
         upper = R * S * H * (S - 1) * (cfg.ssm_state + cfg.ssm_head_dim)
@@ -176,12 +176,33 @@ def test_forward_flops_agree_with_xla(base, kw):
 def test_meta_step_flops_count_twelve_forwards():
     arch = json.loads((CHECKOUT / "bench/configs/mamba2-130m.json")
                       .read_text())["arch"]
-    per_token = flops.forward_flops_per_token(arch, 1024)
-    assert flops.meta_step_flops(arch, K=4, T=1, tb=1, seq=1024) == \
-        12 * 4 * 1024 * per_token
+    per_token = flops.forward_flops_per_token("mamba2", arch, 1024)
+    assert flops.meta_step_flops("mamba2", arch, K=4, T=1, tb=1,
+                                 seq=1024) == 12 * 4 * 1024 * per_token
     with pytest.raises(ValueError):
-        flops.meta_step_flops(dict(arch, meta_mode="reptile"), K=4, T=1,
-                              tb=1, seq=1024)
+        flops.meta_step_flops("mamba2", dict(arch, meta_mode="reptile"),
+                              K=4, T=1, tb=1, seq=1024)
+    with pytest.raises(ValueError, match="family"):
+        flops.meta_step_flops("no_such_family", arch, K=4, T=1, tb=1,
+                              seq=1024)
+
+
+# Each cell's FLOPs a meta-step as the benchmark counted them before the
+# counts moved into the family modules: step_mfu reads the same.
+STEP_FLOPS = {"mamba2-130m.ring4.s1024": 14994401918976.0,
+              "qwen2-1.5b-l2.ring4.s4096": 53219073982464.0,
+              "mamba2-130m.mesh4.s1024": 14994401918976.0}
+
+
+@pytest.mark.parametrize("w", BENCHMARK["workloads"], ids=lambda w: w["name"])
+def test_meta_step_flops_of_each_cell_are_pinned(w):
+    cell = harness.load_cell(w["name"])
+    tr = cell.traffic
+    # global batch 8 is K=4 agents' support and query rows: T=1, tb=1
+    assert tr["global_batch"] == 2 * tr["agents"]
+    assert flops.meta_step_flops(
+        cell.config["reference"], cell.config["arch"], K=tr["agents"], T=1,
+        tb=1, seq=tr["seq_len"]) == STEP_FLOPS[w["name"]]
 
 
 # ---------------------------------------------------------------------------
@@ -249,17 +270,169 @@ def test_a_cell_and_a_metric_are_added_with_files_only(tmp_path):
     assert all(p.read_bytes() == data for p, data in before.items())
 
 
-def test_configurations_match_the_repository():
+# The keys of ArchConfig's meta-learning block: the job, which a
+# configuration file may set apart from the repository's configuration.
+JOB_KEYS = {"placement", "meta_mode", "meta_tasks", "inner_lr",
+            "inner_steps", "topology", "combine", "outer_optimizer",
+            "outer_lr", "hvp_subsample", "inner_freeze", "remat",
+            "remat_span"}
+
+
+def config_faults(cfg: dict, reduced: list) -> list:
+    """What keeps the configuration file ``cfg`` from being the
+    repository's configuration ``cfg["base"]`` with the keys ``reduced``
+    (of ``BENCHMARK.json``) cut and its ``job`` keys set: every other key
+    is the repository's, whether the file states it or leaves it out (it
+    then holds its ``ArchConfig`` default, which ``ArchConfig(**arch)``
+    builds)."""
     from repro.configs import get_config
-    for entry in BENCHMARK["configs"]:
-        cfg = json.loads((CHECKOUT / entry["file"]).read_text())
-        repo = dataclasses.asdict(get_config(cfg["base"]))
-        assert cfg["reduced"] == entry["reduced"]
-        differ = {k for k in repo if repo[k] != cfg["arch"][k]}
-        assert set(cfg["arch"]) == set(repo)
-        assert differ == set(entry["reduced"]), entry["name"]
-        for k in entry["reduced"]:
-            assert cfg["published"][k] == repo[k]
+    from repro.configs.base import ArchConfig
+    repo = dataclasses.asdict(get_config(cfg["base"]))
+    default = {f.name: f.default for f in dataclasses.fields(ArchConfig)}
+    arch, job = cfg["arch"], set(cfg.get("job", []))
+    faults = [f"{k}: not an ArchConfig key" for k in set(arch) - set(repo)]
+    faults += [f"{k}: job key outside the meta-learning block"
+               for k in sorted(job - JOB_KEYS)]
+    if cfg["reduced"] != reduced:
+        faults.append(f"reduced {cfg['reduced']} is not {reduced}")
+    for k in repo:
+        if k not in arch:
+            if default.get(k, dataclasses.MISSING) != repo[k]:
+                faults.append(f"{k}: left out, but the repository's "
+                              f"{repo[k]!r} is not the default")
+        elif k in reduced:
+            if arch[k] == repo[k] or cfg["published"][k] != repo[k]:
+                faults.append(f"{k}: reduced, but not cut from {repo[k]!r}")
+        elif arch[k] != repo[k] and k not in job:
+            faults.append(f"{k}: {arch[k]!r}, the repository's {repo[k]!r}")
+    return faults
+
+
+@pytest.mark.parametrize("entry", BENCHMARK["configs"],
+                         ids=lambda c: c["name"])
+def test_configurations_match_the_repository(entry):
+    cfg = json.loads((CHECKOUT / entry["file"]).read_text())
+    assert config_faults(cfg, entry["reduced"]) == []
+
+
+def test_the_configuration_check_holds_every_shape_key():
+    """A job key may differ; a shape key outside ``reduced`` may not, nor
+    may one left out whose repository value is not the default, nor a job
+    key outside the meta-learning block."""
+    cfg = json.loads((CHECKOUT / "bench/configs/mamba2-130m.json")
+                     .read_text())
+    arch = cfg["arch"]
+    ok = dict(cfg, arch=dict(arch, meta_mode="fomaml", outer_lr=3e-4),
+              job=["meta_mode", "outer_lr"])
+    assert config_faults(ok, []) == []
+    left_out = {k: v for k, v in arch.items() if k != "encoder_frames"}
+    assert config_faults(dict(cfg, arch=left_out), []) == []
+    for bad in (dict(cfg, arch=dict(arch, ssm_state=64)),
+                dict(cfg, arch=dict(arch, ssm_state=64), job=["ssm_state"]),
+                dict(cfg, arch=dict(arch, meta_mode="fomaml")),
+                dict(cfg, arch={k: v for k, v in arch.items()
+                                if k != "ssm_state"}),
+                dict(cfg, arch=dict(arch, extra=1))):
+        assert config_faults(bad, []), bad
+
+
+TOY_FAMILY = '''"""A model family the benchmark has not seen: the program's MoE model at a
+small size, whose expert weights are stacked (experts, d, f)."""
+
+
+def loss(ops, params, tokens, labels, cfg):
+    raise NotImplementedError("no reference is run here")
+
+
+def forward_flops_per_token(arch, seq):
+    d, f, k = arch["d_model"], arch["moe_d_ff"], arch["experts_per_token"]
+    return float(arch["num_layers"] * 6 * d * f * k + 2 * d * arch["vocab_size"])
+
+
+def fan_in(name, core_shape):
+    if name in ("w1", "w2", "w3") and len(core_shape) == 3:
+        return core_shape[1]
+    return None
+'''
+
+TOY_SCRIPT = """
+import json, pathlib, sys
+root = pathlib.Path.cwd()
+sys.path[:0] = [str(root), str(root / "src")]
+import jax
+import numpy as np
+from bench import flops, harness
+from bench.weights import seed_data
+cell = harness.load_cell("toy.tiny")
+prog = harness.build_program(cell, jax.devices()[:1])
+b = prog.bundle
+experts = prog.make_params(seed_data(2**33 + 5))["segments"][1][0]["ffn"]
+print(json.dumps({
+    "flops": flops.meta_step_flops(cell.config["reference"], prog.arch,
+                                   K=b.K, T=b.T, tb=b.tb, seq=32),
+    "std": {n: float(np.std(np.asarray(experts[n], np.float32)))
+            for n in ("w1", "w2", "router")},
+    "arch": prog.arch}))
+"""
+
+
+def test_a_model_family_is_added_with_files_only(tmp_path):
+    """A family the benchmark has not seen joins by files alone: its
+    module beside the references gives the FLOP count and a fan-in rule for
+    its stacked expert weights, and its configuration sets a job apart from
+    the repository's.  The cell loads, builds on one device and counts its
+    FLOPs, and no file the benchmark had changes."""
+    from repro.configs import get_config
+    root = tmp_path / "checkout"
+    shutil.copytree(CHECKOUT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    (root / "src").symlink_to(CHECKOUT / "src")
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    repo = get_config("deepseek-v2-lite-16b")
+    small = dataclasses.asdict(repo.reduced())
+    arch = dict(small, meta_mode="maml", outer_optimizer="adam")
+    del arch["encoder_frames"]                 # left out: the default
+    reduced = sorted(k for k, v in small.items()
+                     if v != getattr(repo, k) and k not in JOB_KEYS)
+    cfg = {"name": "toy", "base": repo.name, "reference": "toy_moe",
+           "reduced": reduced,
+           "published": {k: getattr(repo, k) for k in reduced},
+           "job": ["meta_mode", "outer_optimizer", "remat"], "arch": arch}
+    assert config_faults(cfg, reduced) == []
+    (root / "bench/reference/toy_moe.py").write_text(TOY_FAMILY)
+    (root / "bench/configs/toy.json").write_text(json.dumps(cfg))
+    (root / "bench/traffic/tiny.json").write_text(json.dumps(
+        {"agents": 4, "layout": "stacked", "combine": "dense",
+         "seq_len": 32, "global_batch": 8, "train_domains": 16,
+         "branching": 8, "buckets": 16, "prefetch": 2}))
+    (root / "bench/limits/toy.tiny.json").write_text(
+        json.dumps({"grad_gap": 1}))
+    b = json.loads(json.dumps(BENCHMARK))
+    b["configs"].append({"name": "toy", "source": "test",
+                         "file": "bench/configs/toy.json",
+                         "reduced": reduced, "why": "test"})
+    b["workloads"].append({"name": "toy.tiny", "config": "toy",
+                           "traffic": "tiny", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    env = {**__import__("os").environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run([sys.executable, "-c", TOY_SCRIPT], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    got = json.loads(done.stdout.strip().splitlines()[-1])
+    d, f = small["d_model"], small["moe_d_ff"]
+    per_token = 6 * d * f * 2 * small["num_layers"] + 2 * d * 512
+    assert got["flops"] == 12 * 4 * 1 * 1 * 32 * per_token
+    # experts (4, d, f) draw over d, not over the 4 experts; the router
+    # (d, experts) keeps the default rule
+    assert got["std"]["w1"] == pytest.approx(d ** -0.5, rel=0.05)
+    assert got["std"]["w2"] == pytest.approx(f ** -0.5, rel=0.05)
+    assert got["std"]["router"] == pytest.approx(d ** -0.5, rel=0.1)
+    assert got["arch"]["meta_mode"] == "maml" != repo.meta_mode
+    assert got["arch"]["encoder_frames"] == 0
+    assert all(p.read_bytes() == data for p, data in before.items())
 
 
 # ---------------------------------------------------------------------------
@@ -428,6 +601,43 @@ def test_control_products_are_float8_in_every_pass():
     assert 0.01 < gap(scanned) < 0.5 and 0.01 < gap(unrolled) < 0.5
     assert float(jnp.linalg.norm(scanned - unrolled)) < \
         float(jnp.linalg.norm(scanned - exact))
+
+
+# Digests of each configuration's weights at the repository's small size
+# (``ArchConfig.reduced``), two agents, seed 2**40 + 15, as the benchmark
+# drew them before a family could give its own fan-in rule.
+WEIGHT_DIGESTS = {
+    "mamba2-130m":
+        "860011c14434e9763d8ac48b379016e463c972b79df4b2fcacec3a836517fd56",
+    "qwen2-1.5b-l2":
+        "e91d14be3128ec24adb943693b1a49cdd4ce619682cf3062d7abd7e928900e4b",
+}
+
+
+@pytest.mark.parametrize("entry", BENCHMARK["configs"],
+                         ids=lambda c: c["name"])
+def test_weights_of_each_configuration_are_pinned(entry):
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+    from bench.weights import leaf_name, make_params, seed_data
+    from repro.configs import get_config
+    from repro.models.transformer import build_model
+
+    cfg = json.loads((CHECKOUT / entry["file"]).read_text())
+    model = build_model(get_config(cfg["base"]).reduced())
+    shapes = jax.eval_shape(lambda: model.init(jax.random.key(0)))
+    abstract = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((2,) + s.shape, jnp.bfloat16), shapes)
+    got = jax.jit(lambda k: make_params(abstract, k,
+                                        family=cfg["reference"]))(
+        seed_data(2**40 + 15))
+    digest = hashlib.sha256()
+    for path, x in jax.tree_util.tree_flatten_with_path(got)[0]:
+        digest.update(leaf_name(path).encode())
+        digest.update(np.asarray(x.astype(jnp.float32)).tobytes())
+    assert digest.hexdigest() == WEIGHT_DIGESTS[entry["name"]]
 
 
 def test_mamba2_mixer_weights_follow_the_published_init():

@@ -226,3 +226,94 @@ def test_the_script_prints_the_harness_line_then_the_split(recorded,
     assert set(split["phase_ms"]) >= {"inner_adapt", "hvp"}
     assert split["produce_n"] == 3
     assert split["step_coverage"] > 0.5
+
+
+# ---------------------------------------------------------------------------
+# The per-layer metrics read from the scopes
+# ---------------------------------------------------------------------------
+
+SCOPE_READERS = {"inner_adapt_ms": ("phase_ms", "inner_adapt"),
+                 "outer_grad_ms": ("phase_ms", "outer_grad"),
+                 "hvp_ms": ("phase_ms", "hvp"),
+                 "outer_update_ms": ("phase_ms", "outer_update"),
+                 "combine_ms": ("phase_ms", "combine"),
+                 "mixer_ms": ("block_ms", "mixer"),
+                 "ffn_ms": ("block_ms", "ffn"),
+                 "vocab_head_ms": ("block_ms", "head"),
+                 "produce_ms": ("produce_ms", None)}
+
+
+def _run(**kw):
+    return harness.Run(setup_s=1, window_s=1, steps=4, traced_steps=4,
+                       tokens_per_step=1, step_flops=1, chips=1, peaks=None,
+                       **kw)
+
+
+@pytest.fixture(scope="module")
+def recorded_reading(recorded):
+    """What a traced run of the harness reads from the recorded trace, and
+    what the script's split reads from it."""
+    path, t, s = recorded
+    spans = scopes.program_spans(path)
+    hlo = (DATA / "small_scoped.hlo.txt").read_text()
+    scoped = scopes.per_step(t, spans, hlo, 4)
+    run = _run(trace=trace.summarize(t), scope_ms=scoped["scope_ms"],
+               produce_ms=scoped["produce_ms"])
+    window = next((a, b) for n, a, b in t.spans if n == "bench.window")
+    result = {"notes": {"traced_steps": 4, "steps": 4, "window_s": 0.02},
+              "device": {"window_s": run.trace.window_s}}
+    split = scopes.split(result, scopes.scope_times(t, s), spans, window)
+    return run, scoped, split
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_READERS))
+def test_the_scope_readers_on_the_recorded_trace(recorded_reading, metric):
+    """Each reader gives its scope's device ms a step as the script's split
+    reads it, or nothing where the recorded step has no such scope, and
+    nothing from a run that read no scopes."""
+    run, _, split = recorded_reading
+    read = harness.load_reader(metric)
+    key, scope = SCOPE_READERS[metric]
+    want = split[key] if scope is None else split[key].get(scope)
+    got = read(run)
+    if want is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(want, rel=1e-9) and got > 0
+    assert read(_run()) is None
+
+
+def test_the_recorded_trace_reads_its_scopes_and_notes(recorded_reading):
+    _, scoped, split = recorded_reading
+    present = {m for m in SCOPE_READERS
+               if harness.load_reader(m)(recorded_reading[0]) is not None}
+    assert present == {"inner_adapt_ms", "hvp_ms", "mixer_ms", "produce_ms"}
+    notes = scoped["notes"]
+    assert notes["step_coverage"] == pytest.approx(split["step_coverage"])
+    assert notes["unscoped_ms"]["dif.step"] == pytest.approx(
+        split["phase_ms"]["unscoped"])
+    assert sum(scoped["scope_ms"].values()) == pytest.approx(
+        split["busy_ms"])
+
+
+def test_collective_exposed_ms_on_four_chips():
+    """The exposed part of the collectives, ms a step, mean over four
+    devices; nothing to read where no collective ran."""
+    devices, asyncs = {}, {}
+    for k in range(4):
+        plane = f"/device:TPU:{k}"
+        # on chip k, k ms of a 4 ms permute run beside other work
+        devices[plane] = trace.Ops.of([("fusion.1", 0, k * MS)])
+        asyncs[plane] = trace.Ops.of([("collective-permute-start.2", 0,
+                                       4 * MS)])
+    t = trace.Trace(devices, asyncs, [("bench.window", 0, 10 * MS)])
+    read = harness.load_reader("collective_exposed_ms")
+    run = _run(trace=trace.summarize(t))
+    assert run.trace.n_devices == 4
+    # exposed 4, 3, 2, 1 ms over 4 traced steps
+    assert read(run) == pytest.approx((4 + 3 + 2 + 1) / 4 / 4)
+    quiet = trace.Trace({"/device:TPU:0": trace.Ops.of([("fusion.1", 0,
+                                                         MS)])},
+                        {}, [("bench.window", 0, 10 * MS)])
+    assert read(_run(trace=trace.summarize(quiet))) is None
+    assert read(_run()) is None

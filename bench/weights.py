@@ -25,12 +25,22 @@ The rules go by leaf name, after the leading agent axis (and, under
 fan_in is the product of a weight's contracted dims: the first for all but
 ``wo``/``w_out``, whose first two (heads x head dim) are contracted; a
 depthwise convolution contracts its width alone.
+
+A model family (``bench/reference/<family>.py``, the configuration's
+``reference``) may override the fan-in alone, by a function
+``fan_in(name, core_shape) -> int | None`` of the leaf's name and its shape
+without the agent and layer axes: a number is that leaf's fan-in, ``None``
+leaves it to the rule above.  The rules of the distribution stay as they
+are.  A stacked experts axis is never part of a fan-in: a family whose
+weights stack experts, ``(experts, d, f)``, gives ``d`` for them.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import reference
 
 _TWO_DIM_FAN_IN = ("wo", "w_out")
 _MAMBA_UNIFORM = ("w_x", "w_z", "w_B", "w_C", "w_dt", "w_out",
@@ -55,10 +65,17 @@ def _core_shape(path, shape):
     return shape[lead:]
 
 
-def _leaf(key, path, spec, dtype):
+def _fan_in(rule, name: str, core) -> int:
+    found = rule(name, core) if rule is not None else None
+    if found is not None:
+        return found
+    return int(np.prod(core[:2 if name in _TWO_DIM_FAN_IN else 1]))
+
+
+def _leaf(key, path, spec, dtype, rule):
     name = leaf_name(path).rsplit("/", 1)[-1]
     shape = spec.shape
-    core = _core_shape(path, shape)
+    fan_in = _fan_in(rule, name, _core_shape(path, shape))
     normal = jax.random.normal(key, shape, jnp.float32)
     if name in ("scale", "D"):
         x = jnp.ones(shape, jnp.float32)
@@ -71,25 +88,27 @@ def _leaf(key, path, spec, dtype):
     elif name in ("bq", "bk", "bv", "embed"):
         x = 0.02 * normal
     elif name in _MAMBA_UNIFORM:
-        n = 2 if name in _TWO_DIM_FAN_IN else 1
-        bound = 1.0 / np.sqrt(float(np.prod(core[:n])))
+        bound = 1.0 / np.sqrt(float(fan_in))
         if name == "w_out":                 # over sqrt(layers): shape[1]
             bound /= np.sqrt(float(shape[1]))
         x = jax.random.uniform(key, shape, jnp.float32, -bound, bound)
     else:
-        n = 2 if name in _TWO_DIM_FAN_IN else 1
-        x = normal / np.sqrt(float(np.prod(core[:n])))
+        x = normal / np.sqrt(float(fan_in))
     return x.astype(dtype)
 
 
-def make_params(abstract_params, key_data, dtype=None):
+def make_params(abstract_params, key_data, dtype=None,
+                family: str | None = None):
     """Parameters for ``abstract_params`` (a tree of ShapeDtypeStructs with a
     leading agent axis) from :func:`seed_data`, in ``dtype`` or each leaf's
-    own dtype.  Call under ``jax.jit`` to make them on the device in one
+    own dtype, with the ``fan_in`` rule of the model family ``family`` where
+    it gives one.  Call under ``jax.jit`` to make them on the device in one
     program."""
+    rule = getattr(reference.family(family), "fan_in", None) \
+        if family is not None else None
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract_params)
     key = jax.random.wrap_key_data(jnp.asarray(key_data, jnp.uint32))
     keys = jax.random.split(key, len(flat))
-    leaves = [_leaf(k, path, spec, dtype or spec.dtype)
+    leaves = [_leaf(k, path, spec, dtype or spec.dtype, rule)
               for k, (path, spec) in zip(keys, flat)]
     return jax.tree_util.tree_unflatten(treedef, leaves)
