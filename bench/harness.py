@@ -1,22 +1,48 @@
 """One benchmark run of a Dif-MAML meta-training cell.
 
 The cell (a ``workloads`` entry of ``BENCHMARK.json``) names a
-configuration, ``bench/configs/<config>.json``, and a traffic mix,
-``bench/traffic/<traffic>.json``; its limits are ``bench/limits/<cell>.json``
-and each metric is read by ``bench/metrics/<metric>.py``.  Nothing here
-names a cell, a configuration, a model family or a metric, so a later
-change adds one with files alone:
+configuration and a traffic mix.  Nothing here names a cell, a
+configuration, a model family or a metric, so a later change adds one with
+new files and ``BENCHMARK.json`` entries alone, and edits no file the
+benchmark has:
 
-* a configuration file holds ``arch``, the keyword arguments of the
-  program's ``ArchConfig`` (a key left out takes its default there),
-  ``base``, the repository's configuration it is cut from, ``reduced``, the
-  keys of the model's shape changed from that, and ``job``, the keys of
-  ``ArchConfig``'s meta-learning block that the cell sets apart from it;
-  ``reference`` names its model family;
+* a configuration, ``bench/configs/<config>.json``, holds ``arch``, the
+  keyword arguments of the program's ``ArchConfig`` (a key left out takes
+  its default there), ``base``, the repository's configuration it is cut
+  from, ``reduced``, the keys of the model's shape changed from that, and
+  ``job``, the keys of ``ArchConfig``'s meta-learning block that the cell
+  sets apart from it; ``reference`` names its model family;
 * a model family is one file, ``bench/reference/<family>.py``: its plain
   reference ``loss``, its ``forward_flops_per_token(arch, seq)`` by the
   conventions of ``bench/flops.py``, and, where its weights need one, a
-  ``fan_in(name, core_shape)`` rule for ``bench/weights.py``.
+  ``fan_in(name, core_shape)`` rule for ``bench/weights.py``;
+* a traffic mix is ``bench/traffic/<traffic>.json``, parameters of the one
+  generator, ``bench/traffic.py``;
+* a cell's limits are ``bench/limits/<cell>.json``, from
+  ``bench/calibrate.py``;
+* the tests' pins: ``bench/tests/pins/cells/<cell>.json``
+  (``{"step_flops": ...}``, the FLOPs a meta-step) and
+  ``bench/tests/pins/configs/<config>.json`` (``{"weights_sha256": ...}``,
+  the digest of its weights at the repository's small size); a test whose
+  pin file is missing fails with the value to put there;
+* a metric is read by ``bench/metrics/<metric>.py``, ``read(run) -> float |
+  None``, which returns ``None`` where it finds nothing to read;
+* ``BENCHMARK.json`` gains the ``configs``, ``workloads`` and metric
+  entries, and the cell's name in the ``workloads`` list of each metric
+  it already has that the cell reports (``step_mfu``,
+  ``device_idle_share``, ...).
+
+A reader gets a :class:`Run`: the window's times and step counts, the
+model FLOPs a step and the chips' peaks, with ``--trace 1`` the trace's
+summary and the device time by named scope, and in every run
+
+* ``counters``: each entry of the metrics dict that the program's step
+  returns, stacked over the window's steps (leading axis the step; the
+  traced tail is the last ``traced_steps`` rows), so a counter the step
+  adds reaches a reader with no edit here;
+* ``arch``: ``ArchConfig``'s fields as built;
+* ``traffic``: the resolved ``K``, ``T``, ``tb``, ``seq_len``, ``layout``
+  and ``tokens_per_step``.
 
 A run (:func:`run_cell`):
 
@@ -28,10 +54,12 @@ A run (:func:`run_cell`):
    steps with the program's input pipeline (``TrainBundle.make_pipeline``,
    prefetch thread running) fed by the benchmark's traffic; these steps warm every
    shape the window uses and give the readings that decide ``correct``;
-4. measures a window of back-to-back steps, with no host sync but the last;
+4. measures a window of back-to-back steps, with no host sync but the last,
+   keeping each step's metrics on the device;
    with ``--trace 1`` the profiler records the window's last second or so
    of steps, from a drained queue, as the host span ``bench.window``;
-5. after the window, reads the peak memory, frees the program's state,
+5. after the window, reads the peak memory, fetches the steps' metrics in
+   one transfer, frees the program's state,
    with ``--trace 1`` splits the traced steps' device time by the
    program's named scopes (``bench/scopes.py``), and runs the plain
    reference over the same weights and batches.
@@ -142,6 +170,11 @@ class Run:
     # (bench/scopes.py; None where no such span ran).
     scope_ms: dict | None = None
     produce_ms: float | None = None
+    # The step's metrics dict, each entry stacked over the window's steps
+    counters: dict | None = None
+    arch: dict | None = None          # ArchConfig's fields as built
+    # K, T, tb, seq_len, layout and tokens_per_step as resolved
+    traffic: dict | None = None
 
 
 class CompileClock:
@@ -412,7 +445,7 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
     t_build = time.perf_counter()
     prog = build_program(cell, devices)
     tr = traffic_for(prog, seed)
-    losses = []
+    step_metrics = []
     with pipeline(prog, tr) as pipe:
         t_first = time.perf_counter()
         state, prog_r, step_s = first_steps(prog, pipe, seed)
@@ -436,7 +469,7 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
                     batch = next(pipe)
                 with _annotate("bench.dispatch"):
                     state, metrics = prog.step(state, batch)
-                losses.append(metrics["loss"])
+                step_metrics.append(metrics)
             with _annotate("bench.drain"):
                 jax.block_until_ready(state)
             window_s = time.perf_counter() - t0
@@ -444,9 +477,11 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
     used = list(devices)[:cell.chips]
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in used)
-    failed = int(sum(not math.isfinite(float(x))
-                     for x in jax.device_get(losses)))
-    del state, losses
+    fetched = jax.device_get(step_metrics)
+    counters = {k: np.stack([np.asarray(m[k]) for m in fetched])
+                for k in fetched[0]}
+    failed = int(np.sum(~np.isfinite(counters["loss"])))
+    del state, step_metrics, fetched
     summary = scoped = None
     if trace:
         path = tracing.find_xplane(tdir)
@@ -465,7 +500,12 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
                   tb=tr.tb, seq=tr.seq_len),
               chips=cell.chips, peaks=peaks, trace=summary,
               scope_ms=scoped["scope_ms"] if scoped else None,
-              produce_ms=scoped["produce_ms"] if scoped else None)
+              produce_ms=scoped["produce_ms"] if scoped else None,
+              counters=counters, arch=prog.arch,
+              traffic={"K": tr.K, "T": tr.T, "tb": tr.tb,
+                       "seq_len": tr.seq_len,
+                       "layout": cell.traffic["layout"],
+                       "tokens_per_step": tr.tokens_per_step})
     metrics_out = {}
     for m in cell.metrics:
         value = load_reader(m["name"], cell.root)(run)

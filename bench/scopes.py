@@ -4,7 +4,12 @@ The program names where its work happens with ``jax.named_scope``, in two
 families: the meta-step's phases, ``dif.step.inner_adapt``,
 ``dif.step.outer_grad``, ``dif.step.hvp``, ``dif.step.outer_update`` and
 ``dif.step.combine``; and the model's blocks, ``dif.model.mixer``,
-``dif.model.ffn`` and ``dif.model.head``.  Its input pipeline marks each
+``dif.model.ffn`` and ``dif.model.head``.  A scope nested in another of
+its family takes its ops: ``dif.model.moe_route`` or
+``dif.model.moe_experts`` inside ``dif.model.ffn`` counts under
+``moe_route`` or ``moe_experts`` and out of ``ffn``, so name a router's or
+an expert layer's scope only where ``ffn_ms`` should lose its ops to
+their own readers.  Its input pipeline marks each
 meta-batch its producer thread makes with the host span
 ``dif.pipeline.produce``.
 

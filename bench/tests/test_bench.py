@@ -187,11 +187,20 @@ def test_meta_step_flops_count_twelve_forwards():
                               seq=1024)
 
 
-# Each cell's FLOPs a meta-step as the benchmark counted them before the
-# counts moved into the family modules: step_mfu reads the same.
-STEP_FLOPS = {"mamba2-130m.ring4.s1024": 14994401918976.0,
-              "qwen2-1.5b-l2.ring4.s4096": 53219073982464.0,
-              "mamba2-130m.mesh4.s1024": 14994401918976.0}
+# Each entry's pins sit in a file of its own, which a new entry brings:
+# pins/cells/<cell>.json and pins/configs/<config>.json.
+PINS = pathlib.Path(__file__).resolve().parent / "pins"
+
+
+def pinned(kind: str, name: str, key: str, got):
+    """The value ``key`` pinned for the entry ``name`` in
+    ``pins/<kind>/<name>.json``; a missing file fails the test with the
+    path to add and ``got``, the value the test computed."""
+    path = PINS / kind / f"{name}.json"
+    if not path.is_file():
+        pytest.fail(f"no pin for {name!r}: add {path.relative_to(CHECKOUT)} "
+                    f"holding {json.dumps({key: got})}")
+    return json.loads(path.read_text())[key]
 
 
 @pytest.mark.parametrize("w", BENCHMARK["workloads"], ids=lambda w: w["name"])
@@ -200,9 +209,12 @@ def test_meta_step_flops_of_each_cell_are_pinned(w):
     tr = cell.traffic
     # global batch 8 is K=4 agents' support and query rows: T=1, tb=1
     assert tr["global_batch"] == 2 * tr["agents"]
-    assert flops.meta_step_flops(
+    got = flops.meta_step_flops(
         cell.config["reference"], cell.config["arch"], K=tr["agents"], T=1,
-        tb=1, seq=tr["seq_len"]) == STEP_FLOPS[w["name"]]
+        tb=1, seq=tr["seq_len"])
+    # as the benchmark counted them before the counts moved into the family
+    # modules: step_mfu reads the same
+    assert got == pinned("cells", w["name"], "step_flops", got)
 
 
 # ---------------------------------------------------------------------------
@@ -376,6 +388,23 @@ print(json.dumps({
 """
 
 
+def toy_config(name: str) -> dict:
+    """The toy family's configuration file: the repository's
+    DeepSeek-V2-Lite at its small size (``ArchConfig.reduced``) with a
+    ``maml``/Adam job set apart from it."""
+    from repro.configs import get_config
+    repo = get_config("deepseek-v2-lite-16b")
+    small = dataclasses.asdict(repo.reduced())
+    arch = dict(small, meta_mode="maml", outer_optimizer="adam")
+    del arch["encoder_frames"]                 # left out: the default
+    reduced = sorted(k for k, v in small.items()
+                     if v != getattr(repo, k) and k not in JOB_KEYS)
+    return {"name": name, "base": repo.name, "reference": "toy_moe",
+            "reduced": reduced,
+            "published": {k: getattr(repo, k) for k in reduced},
+            "job": ["meta_mode", "outer_optimizer", "remat"], "arch": arch}
+
+
 def test_a_model_family_is_added_with_files_only(tmp_path):
     """A family the benchmark has not seen joins by files alone: its
     module beside the references gives the FLOP count and a fan-in rule for
@@ -389,16 +418,9 @@ def test_a_model_family_is_added_with_files_only(tmp_path):
     (root / "src").symlink_to(CHECKOUT / "src")
     before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
               if p.is_file()}
-    repo = get_config("deepseek-v2-lite-16b")
-    small = dataclasses.asdict(repo.reduced())
-    arch = dict(small, meta_mode="maml", outer_optimizer="adam")
-    del arch["encoder_frames"]                 # left out: the default
-    reduced = sorted(k for k, v in small.items()
-                     if v != getattr(repo, k) and k not in JOB_KEYS)
-    cfg = {"name": "toy", "base": repo.name, "reference": "toy_moe",
-           "reduced": reduced,
-           "published": {k: getattr(repo, k) for k in reduced},
-           "job": ["meta_mode", "outer_optimizer", "remat"], "arch": arch}
+    cfg = toy_config("toy")
+    small, reduced = cfg["arch"], cfg["reduced"]
+    repo = get_config(cfg["base"])
     assert config_faults(cfg, reduced) == []
     (root / "bench/reference/toy_moe.py").write_text(TOY_FAMILY)
     (root / "bench/configs/toy.json").write_text(json.dumps(cfg))
@@ -508,13 +530,19 @@ def tiny_gaps(tiny):
     on seed 7, and the control on seeds 7-9."""
     import jax
     cell = harness.load_cell("tiny.tiny", root=tiny)
+    refs = {}   # the reference imports nothing of the program: one a seed
+
+    def reference(prog, tr, seed):
+        if seed not in refs:
+            refs[seed] = harness.reference_readings(prog, tr, seed)
+        return refs[seed]
 
     def program_gaps(seed=7):
         prog = harness.build_program(cell, jax.devices())
         tr = harness.traffic_for(prog, seed)
         with harness.pipeline(prog, tr) as pipe:
             _, got, _ = harness.first_steps(prog, pipe, seed)
-        return harness.compare(got, harness.reference_readings(prog, tr, seed))
+        return harness.compare(got, reference(prog, tr, seed))
 
     gaps = {"program": [program_gaps()]}
     for fault in faults.FAULTS:
@@ -524,9 +552,9 @@ def tiny_gaps(tiny):
     gaps["control"] = []
     for seed in (7, 8, 9):
         tr = harness.traffic_for(prog, seed)
-        ref = harness.reference_readings(prog, tr, seed)
         gaps["control"].append(harness.compare(
-            harness.reference_readings(prog, tr, seed, quant=True), ref))
+            harness.reference_readings(prog, tr, seed, quant=True),
+            reference(prog, tr, seed)))
     return gaps
 
 
@@ -603,20 +631,12 @@ def test_control_products_are_float8_in_every_pass():
         float(jnp.linalg.norm(scanned - exact))
 
 
-# Digests of each configuration's weights at the repository's small size
-# (``ArchConfig.reduced``), two agents, seed 2**40 + 15, as the benchmark
-# drew them before a family could give its own fan-in rule.
-WEIGHT_DIGESTS = {
-    "mamba2-130m":
-        "860011c14434e9763d8ac48b379016e463c972b79df4b2fcacec3a836517fd56",
-    "qwen2-1.5b-l2":
-        "e91d14be3128ec24adb943693b1a49cdd4ce619682cf3062d7abd7e928900e4b",
-}
-
-
 @pytest.mark.parametrize("entry", BENCHMARK["configs"],
                          ids=lambda c: c["name"])
 def test_weights_of_each_configuration_are_pinned(entry):
+    """The digest of the configuration's weights at the repository's small
+    size (``ArchConfig.reduced``), two agents, seed 2**40 + 15, as the
+    benchmark drew them before a family could give its own fan-in rule."""
     import hashlib
 
     import jax
@@ -637,7 +657,8 @@ def test_weights_of_each_configuration_are_pinned(entry):
     for path, x in jax.tree_util.tree_flatten_with_path(got)[0]:
         digest.update(leaf_name(path).encode())
         digest.update(np.asarray(x.astype(jnp.float32)).tobytes())
-    assert digest.hexdigest() == WEIGHT_DIGESTS[entry["name"]]
+    found = digest.hexdigest()
+    assert found == pinned("configs", entry["name"], "weights_sha256", found)
 
 
 def test_mamba2_mixer_weights_follow_the_published_init():

@@ -88,6 +88,39 @@ def test_an_unnamed_op_takes_its_users_scopes_else_its_operands():
     assert "fusion.4" not in s and "tuple.7" not in s
 
 
+NESTED_HLO = """\
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(step)/dif.step.outer_grad/dif.model.ffn/dot_general"}
+  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%f, metadata={op_name="jit(step)/dif.step.outer_grad/dif.model.ffn/dif.model.moe_route/dot_general"}
+  ROOT %fusion.3 = f32[8]{0} fusion(%fusion.2), kind=kLoop, calls=%f, metadata={op_name="jit(step)/vmap(dif.step.hvp)/jvp(dif.model.ffn)/transpose(dif.model.moe_experts)/ragged_dot"}
+}
+"""
+
+
+def test_a_model_scope_nested_in_ffn_takes_its_ops():
+    """A ``dif.model.<x>`` scope inside ``dif.model.ffn``, as a router's or
+    an expert layer's would be, takes its ops under ``<x>`` and out of
+    ``ffn``; ``ffn_ms`` then reads the block's other ops alone, and the
+    family's times still add up to the busy time."""
+    assert scopes.scopes_of("jit(step)/dif.step.hvp/dif.model.ffn/"
+                            "dif.model.moe_route/dot") == {
+        "dif.step": "hvp", "dif.model": "moe_route"}
+    t = _trace([("fusion.1", 0, 1 * MS), ("fusion.2", 1 * MS, 3 * MS),
+                ("fusion.3", 3 * MS, 7 * MS)])
+    times = scopes.scope_times(t, scopes.op_scopes(NESTED_HLO))
+    assert times == {("outer_grad", "ffn"): pytest.approx(0.001),
+                     ("outer_grad", "moe_route"): pytest.approx(0.002),
+                     ("hvp", "moe_experts"): pytest.approx(0.004)}
+    run = _run(scope_ms={k: 1e3 * v / 4 for k, v in times.items()})
+    assert harness.load_reader("ffn_ms")(run) == pytest.approx(0.25)
+    assert scopes.ms_under(run, "dif.model", "moe_route") == \
+        pytest.approx(0.5)
+    assert scopes.ms_under(run, "dif.model", "moe_experts") == \
+        pytest.approx(1.0)
+    assert sum(times.values()) == pytest.approx(trace.summarize(t).busy_s)
+
+
 def test_scope_times_count_self_time_under_a_loop_and_the_unscoped():
     t = _trace([("while.3", 0, 8 * MS), ("fusion.2", 1 * MS, 3 * MS),
                 ("add.1", 3 * MS, 4 * MS), ("fusion.1", 8 * MS, 9 * MS),
@@ -294,6 +327,26 @@ def test_the_recorded_trace_reads_its_scopes_and_notes(recorded_reading):
         split["phase_ms"]["unscoped"])
     assert sum(scoped["scope_ms"].values()) == pytest.approx(
         split["busy_ms"])
+
+
+def test_a_scope_nested_in_a_recorded_block_takes_its_time(recorded,
+                                                         recorded_reading):
+    """The recorded step's text with ``dif.model.moe_experts`` nested
+    inside each ``dif.model.mixer``: the nested scope takes all that the
+    block read, inferred ops included, and the block reads nothing."""
+    path, t, _ = recorded
+    run, scoped, _ = recorded_reading
+    hlo = (DATA / "small_scoped.hlo.txt").read_text()
+    nested = hlo.replace("dif.model.mixer/",
+                         "dif.model.mixer/dif.model.moe_experts/")
+    assert nested != hlo
+    got = scopes.per_step(t, scopes.program_spans(path), nested, 4)
+    moved = _run(scope_ms=got["scope_ms"])
+    assert harness.load_reader("mixer_ms")(moved) is None
+    assert scopes.ms_under(moved, "dif.model", "moe_experts") == \
+        pytest.approx(harness.load_reader("mixer_ms")(run), rel=1e-9)
+    assert sum(got["scope_ms"].values()) == pytest.approx(
+        sum(scoped["scope_ms"].values()), rel=1e-9)
 
 
 def test_collective_exposed_ms_on_four_chips():
