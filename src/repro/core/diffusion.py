@@ -230,6 +230,16 @@ def _circular_offsets(A: np.ndarray) -> list[int]:
             if any(A[(k - d) % K, k] > 0 for k in range(K))]
 
 
+def _spec_axes(specs: PyTree) -> list[set[str]]:
+    """For each PartitionSpec leaf of ``specs``, the mesh axes it shards."""
+    from jax.sharding import PartitionSpec
+
+    return [{a for part in s if part is not None
+             for a in ((part,) if isinstance(part, str) else part)}
+            for s in jax.tree.leaves(
+                specs, is_leaf=lambda x: isinstance(x, PartitionSpec))]
+
+
 # ---------------------------------------------------------------------------
 # Combine implementations
 # ---------------------------------------------------------------------------
@@ -341,11 +351,7 @@ def make_mesh_sparse_combine(A: np.ndarray, mesh, axis_name: str,
     # Every axis the specs mention must be manual; any remaining mesh axis
     # stays auto (partial-manual mode — fine on TPU, but XLA:CPU cannot
     # partition it, so CPU callers should pass specs covering their axes).
-    manual = {axis_name}
-    for s in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
-        for part in s:
-            if part is not None:
-                manual.update((part,) if isinstance(part, str) else part)
+    manual = {axis_name}.union(*_spec_axes(specs))
 
     def combine(phi: PyTree) -> PyTree:
         return compat.shard_map(
@@ -471,11 +477,7 @@ def make_mesh_sparse_dynamic_combine(ir, mesh, axis_name: str,
 
     inner = make_sparse_dynamic_combine(ir, axis_name, wire_dtype=wire_dtype)
     specs = in_specs if in_specs is not None else _P(axis_name)
-    manual = {axis_name}
-    for s in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, _P)):
-        for part in s:
-            if part is not None:
-                manual.update((part,) if isinstance(part, str) else part)
+    manual = {axis_name}.union(*_spec_axes(specs))
 
     def combine(phi: PyTree, step=None) -> PyTree:
         if step is None:
@@ -486,6 +488,64 @@ def make_mesh_sparse_dynamic_combine(ir, mesh, axis_name: str,
             axis_names=manual)(phi, step)
 
     return combine
+
+
+def make_mesh_disagreement(mesh, axis_name: str, in_specs: PyTree
+                           ) -> Callable[[PyTree], jax.Array]:
+    """:func:`disagreement` for parameters held one agent per shard of the
+    mesh axis ``axis_name``, with the leaf specs ``in_specs`` (the same
+    contract as :func:`make_mesh_sparse_dynamic_combine`).
+
+    Left to GSPMD, the agent mean of :func:`disagreement` is an f32
+    all-reduce of every leaf at full shape.  Here each shard's block is
+    split K ways along its first dimension that K divides (a block with
+    none is flattened and zero-padded to a multiple of K: a pad's agent
+    mean is zero, so it adds nothing); one tiled ``all_to_all`` gives shard
+    c the c-th piece of every agent's block (bf16 rides as ``u16``, see
+    ``_wire_encode``), a quarter of the all-reduce's bytes at K = 4 on a
+    bf16 wire.  The per-element arithmetic is :func:`disagreement`'s, so
+    under jit the two differ only in the f32 summation order.  Each leaf's
+    squared deviations are summed over the axes its spec shards (a leaf
+    replicated over an axis is counted once) by one scalar ``psum`` per
+    axis set."""
+    from jax.sharding import PartitionSpec as _P
+
+    K = compat.mesh_axis_sizes(mesh)[axis_name]
+    specs = jax.tree.leaves(in_specs, is_leaf=lambda x: isinstance(x, _P))
+    sums_over = [tuple(a for a in mesh.axis_names
+                       if a == axis_name or a in axes)
+                 for axes in _spec_axes(specs)]
+
+    def local(leaves):
+        sums: dict[tuple, jax.Array] = {}
+        for x, axes in zip(leaves, sums_over):
+            d = next((d for d in range(1, x.ndim) if x.shape[d] % K == 0),
+                     None)
+            if d is None:
+                flat = x.reshape(-1)
+                x = jnp.pad(flat, (0, -flat.size % K)).reshape(1, -1)
+                d = 1
+            # the K pieces on an axis of their own, split in place along a
+            # dimension K divides: flattening a tiled block copies it
+            x = x.reshape(x.shape[:d] + (K, x.shape[d] // K) + x.shape[d + 1:])
+            if x.dtype == jnp.bfloat16:
+                wire = jax.lax.bitcast_convert_type(x, jnp.uint16)
+                got = jax.lax.all_to_all(wire, axis_name, d, 0, tiled=True)
+                rows = jax.lax.bitcast_convert_type(got, jnp.bfloat16)
+            else:
+                rows = jax.lax.all_to_all(x, axis_name, d, 0, tiled=True)
+            xc = jnp.mean(rows, axis=0, keepdims=True)
+            sq = jnp.sum((rows - xc).astype(jnp.float32) ** 2)
+            sums[axes] = sums.get(axes, 0.0) + sq
+        total = sum(jax.lax.psum(v, axes) for axes, v in sums.items())
+        return total / K
+
+    def disagreement_fn(params: PyTree) -> jax.Array:
+        leaves = jax.tree.leaves(params)
+        return compat.shard_map(local, mesh, in_specs=(specs,),
+                                out_specs=_P())(leaves)
+
+    return disagreement_fn
 
 
 def centralized_combine(phi: PyTree) -> PyTree:

@@ -249,6 +249,7 @@ def make_meta_step(
     A: np.ndarray | None = None,
     combine_fn: diffusion.CombineFn | None = None,
     freeze_mask: PyTree | None = None,
+    disagreement_fn: Callable[[PyTree], jax.Array] | None = None,
 ):
     """Returns ``step(state, support, query) -> (state, metrics)``:
     the InnerAlgo × DiffusionStrategy × CommSchedule assembly.
@@ -261,7 +262,10 @@ def make_meta_step(
     :func:`schedule_for`.  ``combine_fn`` overrides the combine — mesh-aware
     backends need the leaf PartitionSpecs only the launch layer knows, so
     launch/steps.py builds them via ``diffusion.make_combine`` and injects
-    them here (signature ``combine(phi, step)``).
+    them here (signature ``combine(phi, step)``).  ``disagreement_fn``
+    likewise overrides the ``disagreement`` metric (default
+    :func:`diffusion.disagreement`); launch/steps.py injects
+    :func:`diffusion.make_mesh_disagreement` on agent meshes.
 
     With ``combine_every > 1`` the communication is gated by ``lax.cond``:
     skipped steps execute no combine matmul/collective at all (the old
@@ -296,6 +300,8 @@ def make_meta_step(
     if combine_fn is not None:
         # one name for the diffusion combine, whichever backend made it
         combine_fn = jax.named_scope("dif.step.combine")(combine_fn)
+    disagreement_fn = jax.named_scope("dif.step.disagreement")(
+        disagreement_fn or diffusion.disagreement)
 
     def per_agent(params_k, support_k, query_k):
         return maml.multi_task_meta_grad(
@@ -336,7 +342,7 @@ def make_meta_step(
         metrics = {
             "loss": jnp.mean(losses),
             "per_agent_loss": losses,
-            "disagreement": diffusion.disagreement(params),
+            "disagreement": disagreement_fn(params),
         }
         return TrainState(state.step + 1, params, opt_state), metrics
 

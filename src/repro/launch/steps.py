@@ -243,6 +243,9 @@ class TrainBundle:
     outer_dtype: str = ""         # resolved params/grads storage dtype
     combine_dtype: str = ""       # resolved combine wire format
     combine_backend: str = ""     # resolved combine backend ('auto' applied)
+    # how the disagreement metric reduces over agents: 'stacked' (GSPMD's
+    # agent mean) or 'mesh_all_to_all' (diffusion.make_mesh_disagreement)
+    disagreement_backend: str = "stacked"
 
     def make_eval_harness(self, inner_steps: int | None = None):
         """The in-training recurring-vs-unseen eval engine, bound to this
@@ -454,6 +457,7 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
     else:
         resolved_backend = backend
     combine_fn = None
+    disagreement_fn, disagreement_backend = None, "stacked"
     if backend == "fused":
         # One-pass combine-then-update: make_meta_step builds the fused
         # outer from mcfg (it owns optimizer/strategy/comm wiring); no
@@ -471,6 +475,13 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
         combine_fn = diffusion.make_combine(
             backend, A=A, axis_name=agent_axis, mesh=mesh,
             in_specs=param_specs, combine_dtype=wire_dtype)
+        if agent_mesh and resolved_backend in ("mesh_sparse",
+                                               "mesh_sparse_dynamic"):
+            # one agent a shard: the metric's agent mean rides one
+            # all-to-all instead of GSPMD's f32 all-reduce of every leaf
+            disagreement_fn = diffusion.make_mesh_disagreement(
+                mesh, agent_axis, param_specs)
+            disagreement_backend = "mesh_all_to_all"
     else:
         resolved_backend = "none"
     freeze_mask = None
@@ -483,7 +494,8 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
                                 for k in path),
             abstract(model.specs(), dt))
     step = make_meta_step(model.loss_fn, mcfg, optimizer=opt, A=A,
-                          combine_fn=combine_fn, freeze_mask=freeze_mask)
+                          combine_fn=combine_fn, freeze_mask=freeze_mask,
+                          disagreement_fn=disagreement_fn)
     if agent_mesh:
         # agent dim on the agent axis; the task-batch dim rides intra-agent
         # data parallelism when the mesh has it (2D (agent, model) meshes
@@ -519,7 +531,8 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
                        batch_sh, init_state_fn, loss_fn=model.loss_fn,
                        mcfg=mcfg, schedule=sched, outer_dtype=outer_dtype,
                        combine_dtype=wire_dtype,
-                       combine_backend=resolved_backend)
+                       combine_backend=resolved_backend,
+                       disagreement_backend=disagreement_backend)
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,11 @@
 """The meta step names its phases and the model its blocks.
 
 ``jax.named_scope`` puts ``dif.step.*`` (inner adaptation, outer gradient,
-HVP, outer update, combine) and ``dif.model.*`` (mixer, feed-forward,
-vocabulary head) on the ``op_name`` of every op compiled under them, and
-nothing else: the optimized module is the same program with or without
-them.  The pipeline's producer thread marks each meta-batch it makes with
-the profiler host span ``dif.pipeline.produce``.
+HVP, outer update, combine, the disagreement metric) and ``dif.model.*``
+(mixer, feed-forward, vocabulary head) on the ``op_name`` of every op
+compiled under them, and nothing else: the optimized module is the same
+program with or without them.  The pipeline's producer thread marks each
+meta-batch it makes with the profiler host span ``dif.pipeline.produce``.
 """
 import contextlib
 import dataclasses
@@ -23,7 +23,8 @@ from repro.launch import steps as S
 from repro.launch.mesh import make_host_mesh
 
 STEP = ["dif.step.inner_adapt", "dif.step.outer_grad", "dif.step.hvp",
-        "dif.step.outer_update", "dif.step.combine"]
+        "dif.step.outer_update", "dif.step.combine",
+        "dif.step.disagreement"]
 ARCHS = ["qwen2-1.5b", "mamba2-130m"]
 TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 

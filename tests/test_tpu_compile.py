@@ -106,8 +106,9 @@ def test_fused_combine_update_compiles_for_v5e(one_chip, group_m, dtype):
 @pytest.mark.parametrize("arch", ["qwen2-1.5b", "mamba2-130m"])
 def test_meta_step_scopes_cover_the_chips_ops(topo, arch):
     """At least 90 % of the fusions, dots and convolutions that the chip's
-    compiler leaves an ``op_name`` sit under a ``dif.step.*`` phase; the
-    rest is the step's metrics and what the compiler hoists out of them."""
+    compiler leaves an ``op_name`` sit under a ``dif.step.*`` scope (the
+    ``disagreement`` metric has its own); the rest is the step's other
+    metrics and what the compiler hoists out of them."""
     cfg = dataclasses.replace(get_config(arch).reduced(), remat=True)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     with mesh:
@@ -126,5 +127,5 @@ def test_meta_step_scopes_cover_the_chips_ops(topo, arch):
     phases = {p for n in named for p in re.findall(r"dif\.step\.\w+", n)}
     assert phases == {"dif.step.inner_adapt", "dif.step.outer_grad",
                       "dif.step.hvp", "dif.step.outer_update",
-                      "dif.step.combine"}
+                      "dif.step.combine", "dif.step.disagreement"}
     assert sum("dif.step." in n for n in named) >= 0.9 * len(named)
