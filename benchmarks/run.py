@@ -296,7 +296,7 @@ def bench_combine_strategies(quick: bool):
            "b": jax.random.normal(jax.random.key(1), (K, 4096))}
     nbytes = sum(x.nbytes // K for x in jax.tree.leaves(phi))
     on_tpu = jax.default_backend() == "tpu"
-    backends = ["dense", "sparse_host", "centralized", "pallas"]
+    backends = ["dense", "sparse_host_dynamic", "centralized", "pallas"]
     outs = {}
     for name in backends:
         # interpreter-mode pallas: bigger blocks keep the grid (and the
@@ -314,7 +314,7 @@ def bench_combine_strategies(quick: bool):
     auto = diffusion.select_backend(A)
     emit("combine_auto_selects", 0.0, f"backend={auto}")
     ref = jax.tree.leaves(outs["dense"])
-    for name in ["sparse_host", "pallas"]:
+    for name in ["sparse_host_dynamic", "pallas"]:
         err = max(float(jnp.max(jnp.abs(a - b)))
                   for a, b in zip(ref, jax.tree.leaves(outs[name])))
         emit(f"combine_{name}_equals_dense", 0.0, f"max_err={err:.2e}")
@@ -379,13 +379,13 @@ with mesh:
     ring = topology.build_topology("ring", K)
     rr = topology.make_schedule("round_robin", ring)
     phi_bf = jax.tree.map(lambda x: x.astype(jnp.bfloat16), phi_sh)
-    for bname, Amat, extra in [
-            ("mesh_sparse", ring.matrix, ()),
-            ("mesh_sparse_dynamic", rr.stacked(), (step0,))]:
+    for label, Amat, extra in [
+            ("static", ring.matrix, ()),
+            ("round_robin", rr.stacked(), (step0,))]:
         rec, lint = {}, {}
         for wire in ["float32", "bfloat16"]:
             fn = jax.jit(diffusion.make_combine(
-                bname, A=Amat, mesh=mesh, axis_name="data",
+                "mesh_sparse_dynamic", A=Amat, mesh=mesh, axis_name="data",
                 in_specs={"w": P("data", None)}, combine_dtype=wire))
             txt = fn.lower(phi_bf, *extra).compile().as_text()
             cp = HloCost(txt, n_dev=K).collectives()["per_op"].get(
@@ -408,7 +408,7 @@ with mesh:
         err = float(jnp.max(jnp.abs(
             rec["bfloat16"]["out"]["w"].astype(jnp.float32)
             - rec["float32"]["out"]["w"].astype(jnp.float32))))
-        out["wire_" + bname] = {
+        out["wire_" + label] = {
             "wire_bytes_bf16": rec["bfloat16"]["permute_bytes"],
             "wire_bytes_f32": rec["float32"]["permute_bytes"],
             "by_dtype_bf16": rec["bfloat16"]["by_dtype"],

@@ -15,33 +15,25 @@ combine through it.
 Registered backends
 ===================
 
-``dense``        einsum against the full K×K matrix.  Under pjit with the
-                 agent axis sharded over a mesh axis, XLA lowers this to
-                 all-gather + local reduction: O(K·|w|) collective bytes.
-                 Paper-faithful baseline semantics for arbitrary graphs.
-``sparse_host``  host-roll emulation of the ppermute schedule: one weighted
-                 ``jnp.roll`` per circular neighbor offset.  Under GSPMD a
-                 roll on the agent-sharded dim lowers to collective-permutes
-                 of one shard per offset: O(deg·|w|) bytes.  Exact for *any*
-                 A (offsets with partial support get elementwise-zero
-                 weights), efficient when A is a union of few circular
-                 offsets (ring, torus-on-agent-axis, full graph).
-``sparse``       ``lax.ppermute`` schedule, to be called *inside* an
-                 existing shard_map/manual context where the agent axis is
-                 one-agent-per-shard.
-``mesh_sparse``  production sparse combine: the ``sparse`` schedule wrapped
-                 in a partial-manual shard_map over the agent mesh axis
-                 (built via :mod:`repro.compat`).  Requires jit.
+Every matrix backend takes a ``(K, K)`` matrix, a stacked ``(S, K, K)``
+schedule (step ``i`` mixes with ``A[i % S]``) or a
+:class:`repro.core.topology.ScheduleIR`.
+
+``dense``        einsum against the full K×K matrix (a stacked schedule's
+                 matrix is picked with the traced step).  Under pjit with
+                 the agent axis sharded over a mesh axis, XLA lowers this
+                 to all-gather + local reduction: O(K·|w|) collective
+                 bytes.  Paper-faithful baseline semantics for arbitrary
+                 graphs.
 ``sparse_host_dynamic``
-                 host-roll lowering of a *dynamic* (stacked ``(S, K, K)``)
-                 schedule via its :class:`repro.core.topology.ScheduleIR`:
-                 one weighted roll per offset in the period's offset
+                 host-roll lowering of the matrix's ScheduleIR: one
+                 weighted ``jnp.roll`` per offset in the period's offset
                  *union*, with the per-step weight rows gathered by the
-                 traced step index.  Exact for every schedule kind
+                 traced step index.  Exact for every matrix and schedule
                  (inactive offsets carry zero weights that step); under
-                 GSPMD each roll stays a collective-permute, so dynamic
-                 graphs keep O(deg·|w|) wire instead of the O(K·|w|) the
-                 dense step-indexed einsum pays.
+                 GSPMD each roll stays a collective-permute of one shard,
+                 so sparse graphs keep O(deg·|w|) wire instead of the
+                 O(K·|w|) the dense einsum pays.
 ``sparse_dynamic``
                  the same IR lowered to ``lax.ppermute`` rounds, to be
                  called *inside* an existing shard_map/manual context
@@ -49,7 +41,7 @@ Registered backends
                  steps — only the weight gather sees the step — so one
                  jitted program serves the whole schedule.
 ``mesh_sparse_dynamic``
-                 production dynamic combine: ``sparse_dynamic`` wrapped in
+                 production sparse combine: ``sparse_dynamic`` wrapped in
                  a partial-manual shard_map over the agent mesh axis, step
                  threaded in replicated.  Requires jit.
 ``pallas``       the fused :mod:`repro.kernels.dif_combine` TPU kernel:
@@ -65,10 +57,9 @@ Registered backends
 Agent mesh axis
 ===============
 
-The ``mesh_sparse`` / ``mesh_sparse_dynamic`` backends require the agent
-axis they shard_map over to hold exactly one agent per shard (extent == K).
-Two mesh generations satisfy this (the full contract lives in
-``launch/mesh.py``):
+``mesh_sparse_dynamic`` requires the agent axis it shard_maps over to hold
+exactly one agent per shard (extent == K).  Two mesh generations satisfy
+this (the full contract lives in ``launch/mesh.py``):
 
 * legacy meshes, where the agent graph rides ``data`` (or ``pod`` for
   ``placement='pod'`` archs) — valid only when that axis extent equals K;
@@ -84,9 +75,9 @@ Two mesh generations satisfy this (the full contract lives in
 Wire format
 ===========
 
-The ppermute backends (``sparse``/``mesh_sparse`` and their ``*_dynamic``
-siblings) take a ``combine_dtype`` — the dtype φ travels in on the
-collective-permute rounds:
+The ppermute backends (``sparse_dynamic``/``mesh_sparse_dynamic``) take a
+``combine_dtype`` — the dtype φ travels in on the collective-permute
+rounds:
 
 ``"bfloat16"``   half-width wire.  Each leaf is rounded to bf16 **once**
                  and bitcast to ``uint16`` before the permute rounds, so
@@ -99,12 +90,9 @@ collective-permute rounds:
                  precision value — one rounding on the wire, none
                  compounding across rounds — then cast back to the leaf
                  dtype once.  Combine wire bytes drop 2× vs the f32 wire.
-``"float32"``    full-width wire: φ is promoted to f32 for the rounds and
-                 the mix accumulates in f32 (the escape hatch when bf16
-                 parity is in question).
-``None``         legacy behavior: rounds and accumulation in the leaf's
-                 own dtype (kept for direct callers; the launch layer
-                 always resolves a concrete wire dtype).
+``"float32"``    full-width wire (the default): φ is promoted to f32 for
+                 the rounds and the mix accumulates in f32 (the escape
+                 hatch when bf16 parity is in question).
 
 :func:`resolve_combine_dtype` owns the default: the wire is bf16 exactly
 when the outer (param/grad) dtype is bf16, and ``--combine-dtype f32``
@@ -115,29 +103,27 @@ per-element wire bytes the budget checks (``tree_shard_bytes`` /
 Backend selection
 =================
 
-``make_combine("auto", A=A, mesh=..., axis_name=...)`` picks by topology,
-mesh and accelerator:
+:func:`select_backend` is the one place a combine request becomes a
+registered backend; :func:`make_combine` builds through it, and callers
+that need the resolved name (``launch/steps.py::build_train``) ask it.
+A registered name resolves to itself.  ``"sparse"`` asks for the ppermute
+lowering wherever the step runs: ``mesh_sparse_dynamic`` on a mesh with a
+first-class ``agent`` axis, ``sparse_host_dynamic`` everywhere else (on a
+legacy mesh with the agents on ``data``, GSPMD keeps each roll a
+collective-permute and leaves the TP axes alone, where a partial-manual
+shard_map whose in_specs omit the auto axes would all-gather TP shards at
+entry).  ``"auto"`` picks by topology, mesh and accelerator, reading the
+matrix's ScheduleIR:
 
   1. K == 1                                  → ``none``
-  2. stacked ``(S, K, K)`` schedule whose offset union is sparse
-     (deg < K−1): on a live mesh whose ``axis_name`` extent equals K
-     → ``mesh_sparse_dynamic``; otherwise    → ``sparse_host_dynamic``
-  3. stacked schedule with a dense offset union (e.g. gossip on the
-     full graph)                             → ``dense`` (step-indexed)
-  4. circular-offset-sparse static A (deg < K−1) on a live mesh whose
-     ``axis_name`` extent equals K           → ``mesh_sparse``
-  5. circular-offset-sparse static A, no mesh → ``sparse_host``
-  6. dense A, no mesh, TPU backend           → ``pallas``
+  2. sparse offset union (deg < K−1) on a live mesh whose ``axis_name``
+     extent equals K                         → ``mesh_sparse_dynamic``
+  3. sparse offset union, otherwise          → ``sparse_host_dynamic``
+  4. a static dense ``(K, K)`` matrix, no mesh, TPU backend → ``pallas``
      (on a live mesh the packed layout would break leaf shardings,
-     so dense-einsum keeps the GSPMD lowering)
-  7. otherwise                               → ``dense``
-
-:func:`resolve_schedule_backend` routes an explicitly-requested static
-sparse backend (``sparse``/``sparse_host``/``mesh_sparse``) to its
-``*_dynamic`` sibling when the matrix is a stacked schedule — the permute
-rounds and wire cost are identical, only the weight gather becomes
-step-indexed — and only falls back to ``dense`` (loudly) for backends with
-no dynamic form.
+     so dense-einsum keeps the GSPMD lowering; a stacked schedule with
+     a dense union keeps the step-indexed einsum)
+  5. otherwise                               → ``dense``
 """
 from __future__ import annotations
 
@@ -150,6 +136,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import compat
+from repro.core import topology
 
 PyTree = Any
 # Every registered backend returns ``combine(phi, step=None)``: the optional
@@ -162,9 +149,6 @@ __all__ = [
     "resolve_combine_dtype",
     "wire_elem_bytes",
     "dense_combine",
-    "sparse_combine_host",
-    "make_sparse_combine",
-    "make_mesh_sparse_combine",
     "make_sparse_host_dynamic_combine",
     "make_sparse_dynamic_combine",
     "make_mesh_sparse_dynamic_combine",
@@ -176,7 +160,6 @@ __all__ = [
     "register_backend",
     "combine_backends",
     "select_backend",
-    "resolve_schedule_backend",
     "make_combine",
     "atc_step",
     "cta_step",
@@ -223,13 +206,6 @@ def _wire_decode(r):
     return jax.lax.bitcast_convert_type(r, jnp.bfloat16).astype(jnp.float32)
 
 
-def _circular_offsets(A: np.ndarray) -> list[int]:
-    """Offsets d in [1, K) with any nonzero weight a_{(k-d) mod K, k}."""
-    K = A.shape[0]
-    return [d for d in range(1, K)
-            if any(A[(k - d) % K, k] > 0 for k in range(K))]
-
-
 def _spec_axes(specs: PyTree) -> list[set[str]]:
     """For each PartitionSpec leaf of ``specs``, the mesh axes it shards."""
     from jax.sharding import PartitionSpec
@@ -253,123 +229,15 @@ def dense_combine(A: jax.Array, phi: PyTree) -> PyTree:
     return jax.tree.map(leaf, phi)
 
 
-def sparse_combine_host(A: np.ndarray, phi: PyTree) -> PyTree:
-    """Single-host emulation of the ppermute schedule using jnp.roll.
-
-    Identical math to :func:`make_sparse_combine`; under GSPMD with the
-    agent dim sharded, each roll lowers to a collective-permute while every
-    other (TP) dim keeps its sharding.
-    """
-    A = np.asarray(A)
-    K = A.shape[0]
-    offsets = _circular_offsets(A)
-    self_w = jnp.asarray(np.diagonal(A).copy())
-    off_w = {d: jnp.asarray(np.array([A[(k - d) % K, k] for k in range(K)]))
-             for d in offsets}
-
-    def leaf(x):
-        shape = (K,) + (1,) * (x.ndim - 1)
-        acc = x * self_w.astype(x.dtype).reshape(shape)
-        for d in offsets:
-            # agent k receives from agent (k - d) mod K  ==  roll by +d
-            acc = acc + (off_w[d].astype(x.dtype).reshape(shape)
-                         * jnp.roll(x, d, axis=0))
-        return acc
-
-    return jax.tree.map(leaf, phi)
-
-
-def make_sparse_combine(A: np.ndarray, axis_name: str,
-                        wire_dtype: str | None = None) -> CombineFn:
-    """Collective-permute combine, to be called *inside* shard_map where the
-    leading agent axis is sharded one-agent-per-shard over ``axis_name``.
-
-    Each circular offset ``d`` with any nonzero weight contributes one
-    ``lax.ppermute`` (collective-permute over ICI) plus a per-destination
-    weight multiply.  Self weights are a local scale.  Total collective
-    bytes = (#offsets) · wire_elem_bytes · |w| vs. (K-1)/K · K · |w| for
-    the all-gather that XLA emits for the dense einsum.
-
-    ``wire_dtype``: the wire-format contract of the module docstring —
-    'bfloat16' ships each leaf's one-time bf16 rounding as u16 and
-    accumulates the mix in f32; 'float32' promotes to f32 for the rounds;
-    None keeps the legacy in-dtype math."""
-    A = np.asarray(A)
-    K = A.shape[0]
-    offsets = _circular_offsets(A)
-    self_w = np.diagonal(A).copy()
-    off_w = {d: np.array([A[(k - d) % K, k] for k in range(K)]) for d in offsets}
-    half = wire_dtype == "bfloat16"
-
-    def combine(phi: PyTree) -> PyTree:
-        k = jax.lax.axis_index(axis_name)
-
-        def leaf(x):
-            # x: local block (1, ...) — one agent per shard.
-            if wire_dtype is None:
-                acc = x * jnp.asarray(self_w, x.dtype)[k]
-                for d in offsets:
-                    perm = [(l, (l + d) % K) for l in range(K)]
-                    recv = jax.lax.ppermute(x, axis_name, perm)
-                    acc = acc + recv * jnp.asarray(off_w[d], x.dtype)[k]
-                return acc
-            # f32 accumulation; only neighbor terms pass through the wire
-            send = _wire_encode(x) if half else x.astype(jnp.float32)
-            acc = x.astype(jnp.float32) * jnp.asarray(self_w, jnp.float32)[k]
-            for d in offsets:
-                perm = [(l, (l + d) % K) for l in range(K)]
-                recv = jax.lax.ppermute(send, axis_name, perm)
-                r32 = _wire_decode(recv) if half else recv
-                acc = acc + r32 * jnp.asarray(off_w[d], jnp.float32)[k]
-            return acc.astype(x.dtype)
-
-        return jax.tree.map(leaf, phi)
-
-    return combine
-
-
-def make_mesh_sparse_combine(A: np.ndarray, mesh, axis_name: str,
-                             in_specs: PyTree | None = None,
-                             wire_dtype: str | None = None) -> CombineFn:
-    """Production sparse combine: shard_map over the agent mesh axis with the
-    ppermute schedule of :func:`make_sparse_combine`.  The agent axis is
-    manual; all other axes (e.g. 'model' tensor parallelism) stay auto.
-    Partial-manual shard_map must run under jit (both JAX lines).
-
-    ``in_specs``: pytree of PartitionSpecs matching phi's *actual* shardings
-    (agent dim on ``axis_name`` plus whatever TP axes each leaf carries).
-    Omitting the TP axes would make shard_map all-gather every TP-sharded
-    parameter at entry — measured +77% step wire bytes on qwen2-1.5b — so
-    callers must pass the real specs for TP-sharded trees.
-
-    Wire bytes per device for the exchange itself: (#circular offsets) ×
-    |w_local|, vs. (K−1)/K × K × |w_local| for the dense-einsum all-gather."""
-    from jax.sharding import PartitionSpec as _P
-
-    inner = make_sparse_combine(A, axis_name, wire_dtype=wire_dtype)
-    specs = in_specs if in_specs is not None else _P(axis_name)
-    # Every axis the specs mention must be manual; any remaining mesh axis
-    # stays auto (partial-manual mode — fine on TPU, but XLA:CPU cannot
-    # partition it, so CPU callers should pass specs covering their axes).
-    manual = {axis_name}.union(*_spec_axes(specs))
-
-    def combine(phi: PyTree) -> PyTree:
-        return compat.shard_map(
-            inner, mesh, in_specs=(specs,), out_specs=specs,
-            axis_names=manual)(phi)
-
-    return combine
-
-
 # ---------------------------------------------------------------------------
-# Dynamic-schedule sparse combines: fixed ppermute rounds over the period's
-# offset union, per-step weights gathered with the traced step index
+# ScheduleIR sparse combines: fixed ppermute rounds over the period's offset
+# union, per-step weights gathered with the traced step index (a static
+# matrix is the period-1 schedule)
 # ---------------------------------------------------------------------------
 
-def _ir_for(A):
+def _ir_for(A) -> topology.ScheduleIR:
     """Accept a ScheduleIR, a (K, K) matrix, or a stacked (S, K, K)
     schedule and return the ScheduleIR lowering."""
-    from repro.core import topology
     if isinstance(A, topology.ScheduleIR):
         return A
     return topology.schedule_ir(np.asarray(A))
@@ -419,7 +287,7 @@ def make_sparse_host_dynamic_combine(ir) -> CombineFn:
 
 
 def make_sparse_dynamic_combine(ir, axis_name: str,
-                                wire_dtype: str | None = None) -> CombineFn:
+                                wire_dtype: str = "float32") -> CombineFn:
     """``lax.ppermute`` lowering of a dynamic schedule, to be called
     *inside* shard_map with the agent axis one-agent-per-shard over
     ``axis_name``.
@@ -428,8 +296,9 @@ def make_sparse_dynamic_combine(ir, axis_name: str,
     the whole schedule compiles to one program; only the weight gather
     (two scalar loads per round from the (S, ·, K) tables) sees the step.
     Wire bytes per combine: D · wire_elem_bytes · |w_local| with D = deg
-    of the union.  ``wire_dtype`` follows the module-docstring wire-format
-    contract (None = legacy in-dtype math)."""
+    of the union, vs (K−1)/K · K · |w_local| for the all-gather XLA emits
+    for the dense einsum.  ``wire_dtype`` follows the module-docstring
+    wire-format contract."""
     K, S, offsets = ir.K, ir.period, ir.offsets
     np_self_w = np.asarray(ir.self_weights, np.float32)     # (S, K)
     np_off_w = np.asarray(ir.offset_weights, np.float32)    # (S, D, K)
@@ -442,13 +311,6 @@ def make_sparse_dynamic_combine(ir, axis_name: str,
         ow = jnp.asarray(np_off_w)[s, :, k]      # (D,) this agent's weights
 
         def leaf(x):
-            if wire_dtype is None:
-                acc = x * sw.astype(x.dtype)
-                for i, d in enumerate(offsets):
-                    perm = [(l, (l + d) % K) for l in range(K)]
-                    recv = jax.lax.ppermute(x, axis_name, perm)
-                    acc = acc + recv * ow[i].astype(x.dtype)
-                return acc
             # f32 accumulation; only neighbor terms pass through the wire
             send = _wire_encode(x) if half else x.astype(jnp.float32)
             acc = x.astype(jnp.float32) * sw
@@ -466,17 +328,27 @@ def make_sparse_dynamic_combine(ir, axis_name: str,
 
 def make_mesh_sparse_dynamic_combine(ir, mesh, axis_name: str,
                                      in_specs: PyTree | None = None,
-                                     wire_dtype: str | None = None
+                                     wire_dtype: str = "float32"
                                      ) -> CombineFn:
-    """Production dynamic combine: shard_map over the agent mesh axis with
+    """Production sparse combine: shard_map over the agent mesh axis with
     the :func:`make_sparse_dynamic_combine` rounds; the step index rides in
-    replicated.  Same in_specs contract as :func:`make_mesh_sparse_combine`
-    (pass the real leaf specs for TP-sharded trees or shard_map all-gathers
-    them at entry)."""
+    replicated.  The agent axis is manual; all other axes (e.g. 'model'
+    tensor parallelism) stay auto.  Partial-manual shard_map must run
+    under jit.
+
+    ``in_specs``: pytree of PartitionSpecs matching phi's *actual*
+    shardings (agent dim on ``axis_name`` plus whatever TP axes each leaf
+    carries).  Omitting the TP axes would make shard_map all-gather every
+    TP-sharded parameter at entry — measured +77% step wire bytes on
+    qwen2-1.5b — so callers must pass the real specs for TP-sharded
+    trees."""
     from jax.sharding import PartitionSpec as _P
 
     inner = make_sparse_dynamic_combine(ir, axis_name, wire_dtype=wire_dtype)
     specs = in_specs if in_specs is not None else _P(axis_name)
+    # Every axis the specs mention must be manual; any remaining mesh axis
+    # stays auto (partial-manual mode — fine on TPU, but XLA:CPU cannot
+    # partition it, so CPU callers should pass specs covering their axes).
     manual = {axis_name}.union(*_spec_axes(specs))
 
     def combine(phi: PyTree, step=None) -> PyTree:
@@ -717,67 +589,31 @@ def _stacked(Aj: jax.Array, apply: Callable[[jax.Array, PyTree], PyTree]
     return combine
 
 
-# Static sparse backend -> its stacked-schedule-capable sibling: the same
-# ppermute rounds, with the per-step weight rows gathered by the traced step.
-_DYNAMIC_SIBLING = {"sparse": "sparse_dynamic",
-                    "sparse_host": "sparse_host_dynamic",
-                    "mesh_sparse": "mesh_sparse_dynamic"}
-
-
-def _reject_stacked(A, name: str) -> np.ndarray:
-    A = np.asarray(A)
-    if A.ndim == 3:
-        raise ValueError(
-            f"combine backend {name!r} precomputes a static per-offset "
-            f"permute schedule and cannot serve a stacked ({A.shape[0]}-"
-            f"step) matrix schedule; use its dynamic sibling "
-            f"{_DYNAMIC_SIBLING[name]!r} (same O(deg·|w|) ppermute rounds, "
-            f"weights gathered with the traced step) — or the step-indexed "
-            f"'dense'/'pallas' dense fallbacks")
-    return A
+def _matrix(A) -> jax.Array:
+    """``A`` for the backends that contract the matrix itself: a ScheduleIR
+    gives back its ``(K, K)`` matrix, or its ``(S, K, K)`` stack when
+    ``S > 1``."""
+    if isinstance(A, topology.ScheduleIR):
+        A = A.matrix_at(0) if A.period == 1 else A.stacked()
+    return jnp.asarray(A)
 
 
 @register_backend("dense")
 def _build_dense(*, A, **_ctx) -> CombineFn:
-    Aj = jnp.asarray(A)
+    Aj = _matrix(A)
     if Aj.ndim == 3:
         return _stacked(Aj, dense_combine)
     return _stepless(functools.partial(dense_combine, Aj))
 
 
-@register_backend("sparse_host", emits_permutes=True)
-def _build_sparse_host(*, A, **_ctx) -> CombineFn:
-    return _stepless(functools.partial(
-        sparse_combine_host, _reject_stacked(A, "sparse_host")))
-
-
-@register_backend("sparse", needs_axis_name=True, emits_permutes=True)
-def _build_sparse(*, A, axis_name, combine_dtype=None, **_ctx) -> CombineFn:
-    return _stepless(make_sparse_combine(_reject_stacked(A, "sparse"),
-                                         axis_name, wire_dtype=combine_dtype))
-
-
-@register_backend("mesh_sparse", needs_mesh=True, needs_axis_name=True,
-                  emits_permutes=True)
-def _build_mesh_sparse(*, A, mesh, axis_name, in_specs=None,
-                       combine_dtype=None, **_ctx) -> CombineFn:
-    A = _reject_stacked(A, "mesh_sparse")
-    K = A.shape[0]
-    _check_agent_extent("mesh_sparse", mesh, axis_name, K)
-    return _stepless(make_mesh_sparse_combine(A, mesh, axis_name,
-                                              in_specs=in_specs,
-                                              wire_dtype=combine_dtype))
-
-
-def _check_agent_extent(name: str, mesh, axis_name: str, K: int) -> None:
+def _check_agent_extent(mesh, axis_name: str, K: int) -> None:
     extent = compat.mesh_axis_sizes(mesh).get(axis_name)
     if extent != K:
         raise ValueError(
-            f"{name} needs one agent per shard: axis {axis_name!r} has "
-            f"extent {extent} but the schedule is over K={K} agents. Use "
-            f"'sparse_host{'_dynamic' if 'dynamic' in name else ''}' when "
-            f"the agent axis spans multiple mesh axes (e.g. multi-pod data "
-            f"placement).")
+            f"mesh_sparse_dynamic needs one agent per shard: axis "
+            f"{axis_name!r} has extent {extent} but the schedule is over "
+            f"K={K} agents. Use 'sparse_host_dynamic' when the agent axis "
+            f"spans multiple mesh axes (e.g. multi-pod data placement).")
 
 
 @register_backend("sparse_host_dynamic", emits_permutes=True)
@@ -787,7 +623,7 @@ def _build_sparse_host_dynamic(*, A, **_ctx) -> CombineFn:
 
 @register_backend("sparse_dynamic", needs_axis_name=True,
                   emits_permutes=True)
-def _build_sparse_dynamic(*, A, axis_name, combine_dtype=None, **_ctx
+def _build_sparse_dynamic(*, A, axis_name, combine_dtype, **_ctx
                           ) -> CombineFn:
     return make_sparse_dynamic_combine(_ir_for(A), axis_name,
                                        wire_dtype=combine_dtype)
@@ -796,9 +632,9 @@ def _build_sparse_dynamic(*, A, axis_name, combine_dtype=None, **_ctx
 @register_backend("mesh_sparse_dynamic", needs_mesh=True,
                   needs_axis_name=True, emits_permutes=True)
 def _build_mesh_sparse_dynamic(*, A, mesh, axis_name, in_specs=None,
-                               combine_dtype=None, **_ctx) -> CombineFn:
+                               combine_dtype, **_ctx) -> CombineFn:
     ir = _ir_for(A)
-    _check_agent_extent("mesh_sparse_dynamic", mesh, axis_name, ir.K)
+    _check_agent_extent(mesh, axis_name, ir.K)
     return make_mesh_sparse_dynamic_combine(ir, mesh, axis_name,
                                             in_specs=in_specs,
                                             wire_dtype=combine_dtype)
@@ -806,26 +642,12 @@ def _build_mesh_sparse_dynamic(*, A, mesh, axis_name, in_specs=None,
 
 @register_backend("pallas")
 def _build_pallas(*, A, block_m=512, interpret=None, **_ctx) -> CombineFn:
-    Aj = jnp.asarray(A)
+    Aj = _matrix(A)
     if Aj.ndim == 3:
         return _stacked(Aj, functools.partial(_pallas_apply, block_m=block_m,
                                               interpret=interpret))
     return _stepless(make_pallas_combine(Aj, block_m=block_m,
                                          interpret=interpret))
-
-
-@register_backend("fused")
-def _build_fused(*, A, block_m=512, interpret=None, **_ctx) -> CombineFn:
-    """Combine-only face of the fused outer backend.
-
-    Selecting ``backend='fused'`` moves the whole clip→moments→combine
-    chain into :func:`repro.core.fused.make_fused_outer` — the trainer
-    threads that path itself.  The registry entry exists for the two spots
-    that still need a plain combine under that name: the cta pre-mix (which
-    runs *before* the gradient and therefore cannot fuse with the update)
-    and direct ``make_combine('fused')`` callers; both get the packed
-    one-pass pallas combine."""
-    return _build_pallas(A=A, block_m=block_m, interpret=interpret)
 
 
 def _pallas_apply(A: jax.Array, phi: PyTree, *, block_m: int = 512,
@@ -852,131 +674,79 @@ def _build_none(**_ctx) -> CombineFn:
     return _stepless(no_combine)
 
 
-def select_backend(A: np.ndarray | None, *, mesh=None,
-                   axis_name: str | None = None) -> str:
-    """Pick a backend name from topology, mesh and accelerator (see module
-    docstring for the rule table).
+def select_backend(A, *, mesh=None, axis_name: str | None = None,
+                   request: str = "auto") -> str:
+    """The registered backend a combine ``request`` lowers to (module
+    docstring, "Backend selection"): a registered name is itself;
+    ``'sparse'`` and ``'auto'`` resolve from what the call can observe —
+    the matrix's ScheduleIR degree, the mesh and the agent axis's extent.
 
     A mesh with a first-class ``agent`` axis announces the agent extent
     itself: when ``axis_name`` is not given it defaults to ``'agent'`` on
     such meshes, so 2D ``(agent, model)`` production meshes route sparse
-    topologies to the shard_mapped backends without the caller having to
+    topologies to the shard_mapped backend without the caller having to
     know which mesh generation it is on."""
-    if mesh is not None and axis_name is None:
-        if "agent" in getattr(mesh, "axis_names", ()):
-            axis_name = "agent"
+    if request in _BACKENDS:
+        return request
+    agent_mesh = "agent" in getattr(mesh, "axis_names", ())
+    if request == "sparse":
+        return "mesh_sparse_dynamic" if agent_mesh else "sparse_host_dynamic"
+    if request != "auto":
+        raise ValueError(
+            f"unknown combine strategy {request!r}; registered: "
+            f"{combine_backends()}, or 'auto' / 'sparse'")
+    if agent_mesh and axis_name is None:
+        axis_name = "agent"
     if A is None:
         return "dense"
-    from repro.core import topology as _topo
-    if isinstance(A, _topo.ScheduleIR):
-        A = A.stacked()
-    A = np.asarray(A)
-    if A.ndim == 3:
-        # stacked per-step schedule: a sparse offset union lowers to fixed
-        # ppermute rounds with step-gathered weights; a dense union (e.g.
-        # gossip on the full graph) keeps the step-indexed dense einsum
-        ir = _ir_for(A)
-        if ir.K == 1:
-            return "none"
-        if ir.degree < ir.K - 1:
-            if (mesh is not None and axis_name is not None
-                    and compat.mesh_axis_sizes(mesh).get(axis_name) == ir.K):
-                return "mesh_sparse_dynamic"
-            return "sparse_host_dynamic"
-        return "dense"
-    K = A.shape[0]
-    if K == 1:
+    ir = _ir_for(A)
+    if ir.K == 1:
         return "none"
-    degree = len(_circular_offsets(A))
-    sparse_wins = degree < K - 1          # strictly fewer collectives than
-    if sparse_wins and mesh is not None and axis_name is not None:
-        if compat.mesh_axis_sizes(mesh).get(axis_name) == K:
-            return "mesh_sparse"
-    if sparse_wins:
-        return "sparse_host"
-    if mesh is None and jax.default_backend() == "tpu":
-        # fused one-pass dense reduction; only off-mesh — pack_pytree's
-        # concatenate would destroy leaf shardings on a live mesh, forcing
-        # an all-gather of every TP shard
+    if ir.degree < ir.K - 1:          # strictly fewer rounds than a gather
+        if (mesh is not None and axis_name is not None
+                and compat.mesh_axis_sizes(mesh).get(axis_name) == ir.K):
+            return "mesh_sparse_dynamic"
+        return "sparse_host_dynamic"
+    if mesh is None and np.ndim(A) == 2 and jax.default_backend() == "tpu":
+        # fused one-pass dense reduction of a static matrix; only off-mesh
+        # — pack_pytree's concatenate would destroy leaf shardings on a
+        # live mesh, forcing an all-gather of every TP shard
         return "pallas"
     return "dense"
 
 
-# Backends able to serve a stacked (S, K, K) schedule with the traced step.
-_STEP_INDEXED_BACKENDS = ("dense", "pallas", "fused", "sparse_dynamic",
-                          "sparse_host_dynamic", "mesh_sparse_dynamic")
-
-
-def resolve_schedule_backend(backend: str, A) -> str:
-    """Route ``backend`` to a stacked-schedule-capable equivalent when ``A``
-    is a stacked schedule ('auto' resolves itself in
-    :func:`select_backend`).  The single owner of the capability list —
-    trainer and launch both route through here.
-
-    The static sparse backends upgrade silently to their ``*_dynamic``
-    siblings: identical permute rounds and O(deg·|w|) wire, only the weight
-    gather becomes step-indexed.  A backend with no dynamic form falls back
-    to 'dense' — loudly, because that gives up the sparse wire cost."""
-    if (backend != "auto" and A is not None
-            and np.asarray(A).ndim == 3
-            and backend not in _STEP_INDEXED_BACKENDS):
-        b = _BACKENDS.get(backend)
-        if b is not None and not b.needs_matrix:
-            return backend           # matrix-free (none/centralized): no-op
-        sibling = _DYNAMIC_SIBLING.get(backend)
-        if sibling is not None:
-            return sibling
-        import warnings
-        warnings.warn(
-            f"combine backend {backend!r} cannot step-index a stacked "
-            f"({np.asarray(A).shape[0]}-step) matrix schedule; falling back "
-            f"to 'dense' — collective bytes rise from O(deg·|w|) to "
-            f"O(K·|w|). Use a static schedule to keep {backend!r}.",
-            RuntimeWarning, stacklevel=3)
-        return "dense"
-    return backend
-
-
-def make_combine(strategy: str, A: np.ndarray | None = None,
-                 axis_name: str | None = None, *, mesh=None,
-                 in_specs: PyTree | None = None, block_m: int = 512,
-                 interpret: bool | None = None,
-                 combine_dtype: str | None = None) -> CombineFn:
-    """Single entry point: build a combine fn from a backend name or 'auto'.
-
-    ``strategy``: 'auto' | any :func:`combine_backends` name.  'auto'
-    resolves via :func:`select_backend`.
+def make_combine(strategy: str, A=None, axis_name: str | None = None, *,
+                 mesh=None, in_specs: PyTree | None = None,
+                 block_m: int = 512, interpret: bool | None = None,
+                 combine_dtype: str = "float32") -> CombineFn:
+    """Single entry point: build a combine fn from a request — any
+    :func:`combine_backends` name, ``'sparse'`` or ``'auto'`` — resolved
+    by :func:`select_backend`.
 
     ``A`` may be one ``(K, K)`` matrix, a stacked ``(S, K, K)`` schedule
-    (see :class:`repro.core.topology.TopologySchedule`), or — for the
-    ``*_dynamic`` backends — a pre-lowered
-    :class:`repro.core.topology.ScheduleIR`.  Stacked schedules are served
-    at O(deg·|w|) wire by the ``sparse_dynamic`` family (fixed ppermute
-    rounds, weights gathered with the step passed to
-    ``combine(phi, step)``) and at O(K·|w|) by the step-indexed
-    'dense'/'pallas' fallbacks.
+    (see :class:`repro.core.topology.TopologySchedule`), or a pre-lowered
+    :class:`repro.core.topology.ScheduleIR`.  Sparse graphs are served at
+    O(deg·|w|) wire by the ``*_dynamic`` ppermute family (fixed rounds,
+    a stacked schedule's weights gathered with the step passed to
+    ``combine(phi, step)``) and at O(K·|w|) by 'dense'/'pallas'.
 
     ``combine_dtype``: wire format for the ppermute backends (see the
-    module docstring) — 'bfloat16' | 'float32' | None (legacy in-dtype).
-    Backends without a wire (dense, pallas, host rolls, …) ignore it.
+    module docstring) — 'bfloat16' | 'float32'.  Backends without a wire
+    (dense, pallas, host rolls, …) ignore it.
     """
-    if strategy == "auto":
-        strategy = select_backend(A, mesh=mesh, axis_name=axis_name)
-    if combine_dtype is not None and combine_dtype not in WIRE_DTYPES:
+    if combine_dtype not in WIRE_DTYPES:
         raise ValueError(
             f"combine_dtype {combine_dtype!r} is not a supported wire "
             f"format; pick one of {sorted(WIRE_DTYPES)}")
-    backend = _BACKENDS.get(strategy)
-    if backend is None:
-        raise ValueError(
-            f"unknown combine strategy {strategy!r}; "
-            f"registered: {combine_backends()}")
+    name = select_backend(A, mesh=mesh, axis_name=axis_name,
+                          request=strategy)
+    backend = _BACKENDS[name]
     if backend.needs_matrix:
-        assert A is not None, f"{strategy!r} combine needs a matrix A"
+        assert A is not None, f"{name!r} combine needs a matrix A"
     if backend.needs_axis_name:
-        assert axis_name is not None, f"{strategy!r} combine needs axis_name"
+        assert axis_name is not None, f"{name!r} combine needs axis_name"
     if backend.needs_mesh:
-        assert mesh is not None, f"{strategy!r} combine needs a mesh"
+        assert mesh is not None, f"{name!r} combine needs a mesh"
     return backend.build(A=A, axis_name=axis_name, mesh=mesh,
                          in_specs=in_specs, block_m=block_m,
                          interpret=interpret, combine_dtype=combine_dtype)
@@ -986,17 +756,16 @@ def combine_wire_bytes(A: np.ndarray, strategy: str, model_bytes: int) -> int:
     """Per-step collective-byte model for a backend (benchmark reporting).
 
     ``model_bytes``: size of one agent's launch model.  dense/pallas gather
-    K−1 remote models; sparse (static or dynamic) moves one model per
-    offset of the (union) permute schedule; centralized is a
-    reduce+broadcast (2·(K−1)/K); none moves nothing.  ``A`` may be a
-    ``(K, K)`` matrix or a stacked ``(S, K, K)`` schedule.
+    K−1 remote models; the ppermute backends move one model per offset of
+    the (union) permute schedule; centralized is a reduce+broadcast
+    (2·(K−1)/K); none moves nothing.  ``A`` may be a ``(K, K)`` matrix or
+    a stacked ``(S, K, K)`` schedule.
     """
     A = np.asarray(A)
     K = A.shape[-1]
-    if strategy in ("none",):
+    if strategy == "none":
         return 0
-    if strategy in ("sparse", "sparse_host", "mesh_sparse",
-                    "sparse_dynamic", "sparse_host_dynamic",
+    if strategy in ("sparse_dynamic", "sparse_host_dynamic",
                     "mesh_sparse_dynamic"):
         return _ir_for(A).degree * model_bytes
     if strategy == "centralized":

@@ -87,7 +87,7 @@ class UpdateConfig:
 
     strategy: str = "atc"             # update.update_strategies() name
     inner: str = "maml"               # update.inner_algos() name
-    backend: str = "dense"            # 'auto' | diffusion.combine_backends() name
+    backend: str = "dense"            # diffusion.make_combine request | 'fused'
     combine_every: int = 1            # CommSchedule cadence
 
 
@@ -291,12 +291,10 @@ def make_meta_step(
             and (fused_outer is None or strategy.pre_combine)):
         if A is None:
             A = schedule_for(cfg).stacked()
-        backend = uc.backend
-        if backend in ("sparse", "mesh_sparse"):
-            # host-level default; mesh version injected by launch/
-            backend = "sparse_host"
-        backend = diffusion.resolve_schedule_backend(backend, A)
-        combine_fn = diffusion.make_combine(backend, A=A)
+        # the fused outer step mixes inside its kernel; the cta pre-mix
+        # runs before the gradient, so it takes the one-pass pallas combine
+        combine_fn = diffusion.make_combine(
+            "pallas" if fused_outer is not None else uc.backend, A=A)
     if combine_fn is not None:
         # one name for the diffusion combine, whichever backend made it
         combine_fn = jax.named_scope("dif.step.combine")(combine_fn)

@@ -283,10 +283,10 @@ def main() -> None:
                          "model), collapsing to 2D (agent, model) — instead "
                          "of the legacy placement-driven meshes")
     ap.add_argument("--combine", default=None,
-                    help="combine backend override: 'auto' or any "
-                         "repro.core.diffusion.combine_backends() name "
-                         "(dense | sparse | sparse_host | mesh_sparse | "
-                         "sparse_dynamic | sparse_host_dynamic | "
+                    help="combine request, resolved by "
+                         "repro.core.diffusion.select_backend: 'auto', "
+                         "'sparse' or any combine_backends() name (dense | "
+                         "sparse_host_dynamic | sparse_dynamic | "
                          "mesh_sparse_dynamic | pallas | centralized | none)")
     ap.add_argument("--assert-budgets", action="store_true",
                     help="fail if the agent-mesh combine leaves the "

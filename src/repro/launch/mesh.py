@@ -16,10 +16,10 @@ Every mesh in this repo is built from (a subset of) four named axes:
             graph lives on this axis and on nothing else.  When present it
             is the leading axis, the ``agent`` *logical* axis of the
             stacked parameter tree maps onto it 1:1
-            (``sharding/rules.py``), and the ``mesh_sparse`` /
-            ``mesh_sparse_dynamic`` combine backends shard_map their
-            ``lax.ppermute`` rounds over it (they require extent == K, one
-            agent per shard — see :mod:`repro.core.diffusion`).
+            (``sharding/rules.py``), and the ``mesh_sparse_dynamic``
+            combine backend shard_maps its ``lax.ppermute`` rounds over it
+            (it requires extent == K, one agent per shard — see
+            :mod:`repro.core.diffusion`).
 ``data``    intra-agent batch/FSDP parallelism.  On legacy meshes without
             an ``agent`` axis it doubles as the agent axis for
             ``placement='data'`` archs (one agent per data slice).

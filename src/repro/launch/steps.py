@@ -242,7 +242,7 @@ class TrainBundle:
     schedule: Any = None          # TopologySchedule (None when K == 1)
     outer_dtype: str = ""         # resolved params/grads storage dtype
     combine_dtype: str = ""       # resolved combine wire format
-    combine_backend: str = ""     # resolved combine backend ('auto' applied)
+    combine_backend: str = ""     # diffusion.select_backend's resolution
     # how the disagreement metric reduces over agents: 'stacked' (GSPMD's
     # agent mean) or 'mesh_all_to_all' (diffusion.make_mesh_disagreement)
     disagreement_backend: str = "stacked"
@@ -435,27 +435,6 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
     strat_obj = update.get_strategy(
         mcfg.update_config.strategy if K > 1 else "none")
     backend = mcfg.update_config.backend
-    if backend == "sparse":
-        # Sparse neighbor combine.  On an agent-axis mesh the shard_map
-        # form is always valid (extent == K by construction) and gets the
-        # real leaf specs below.  On legacy meshes: weighted rolls over the
-        # agent-sharded dim — under GSPMD each roll lowers to collective-
-        # permutes of one shard per circular offset, while every other (TP)
-        # dim keeps its sharding; a partial-manual shard_map whose in_specs
-        # omit the auto axes would instead all-gather TP shards at entry
-        # (measured +77% wire).
-        backend = "mesh_sparse" if agent_mesh else "sparse_host"
-    # Stacked (dynamic) schedules: static sparse backends upgrade to their
-    # *_dynamic siblings (same permute rounds, step-gathered weights)
-    backend = diffusion.resolve_schedule_backend(backend, A)
-    # The name the lint layer sees must be the backend actually lowered —
-    # resolve 'auto' the same way make_combine will, and record 'none'
-    # when no combine is injected at all (K=1 / strategies without one).
-    if backend == "auto":
-        resolved_backend = diffusion.select_backend(A, mesh=mesh,
-                                                    axis_name=agent_axis)
-    else:
-        resolved_backend = backend
     combine_fn = None
     disagreement_fn, disagreement_backend = None, "stacked"
     if backend == "fused":
@@ -468,22 +447,26 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
             raise ValueError(
                 "backend='fused' runs the packed single-host kernel layout "
                 "and cannot serve a mesh with a first-class agent axis "
-                f"(mesh axes {mesh.axis_names}); use 'sparse'/'mesh_sparse' "
-                "there, or a host mesh for the fused outer step.")
+                f"(mesh axes {mesh.axis_names}); use 'sparse' there, or a "
+                "host mesh for the fused outer step.")
     elif strat_obj.needs_combine_fn and K > 1:
         param_specs = jax.tree.map(lambda s: s.spec, params_sh)
         combine_fn = diffusion.make_combine(
             backend, A=A, axis_name=agent_axis, mesh=mesh,
             in_specs=param_specs, combine_dtype=wire_dtype)
-        if agent_mesh and resolved_backend in ("mesh_sparse",
-                                               "mesh_sparse_dynamic"):
+        # the name the lint layer and TrainBundle see is the backend
+        # make_combine lowered
+        backend = diffusion.select_backend(
+            A, mesh=mesh, axis_name=agent_axis, request=backend)
+        if agent_mesh and backend == "mesh_sparse_dynamic":
             # one agent a shard: the metric's agent mean rides one
             # all-to-all instead of GSPMD's f32 all-reduce of every leaf
             disagreement_fn = diffusion.make_mesh_disagreement(
                 mesh, agent_axis, param_specs)
             disagreement_backend = "mesh_all_to_all"
     else:
-        resolved_backend = "none"
+        # no combine is injected at all (K=1 / strategies without one)
+        backend = "none"
     freeze_mask = None
     if cfg.inner_freeze:
         # ANIL-style: the named subtree (e.g. 'encoder') is frozen in the
@@ -531,7 +514,7 @@ def build_train(cfg: ArchConfig, mesh: Mesh,
                        batch_sh, init_state_fn, loss_fn=model.loss_fn,
                        mcfg=mcfg, schedule=sched, outer_dtype=outer_dtype,
                        combine_dtype=wire_dtype,
-                       combine_backend=resolved_backend,
+                       combine_backend=backend,
                        disagreement_backend=disagreement_backend)
 
 

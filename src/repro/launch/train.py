@@ -152,7 +152,8 @@ def main(argv: list[str] | None = None) -> dict:
                          "--steps divisible by C to avoid one extra "
                          "compile for the final partial dispatch")
     ap.add_argument("--combine", default=None,
-                    help="combine backend override: 'auto' or any "
+                    help="combine request, resolved by "
+                         "diffusion.select_backend: 'auto', 'sparse' or any "
                          "diffusion.combine_backends() name")
     ap.add_argument("--strategy", default=None,
                     choices=sorted(update.update_strategies()),
@@ -168,7 +169,7 @@ def main(argv: list[str] | None = None) -> dict:
                          "--topology-schedule link_failure")
     ap.add_argument("--fused-outer", action="store_true",
                     help="run the one-pass combine-then-update outer step "
-                         "(shorthand for --combine fused): clip scale, "
+                         "(the trainer mode backend='fused'): clip scale, "
                          "optimizer moments and launch-model mix in a "
                          "single kernel sweep over the parameter bytes")
     ap.add_argument("--outer-dtype", default=None,
@@ -186,8 +187,8 @@ def main(argv: list[str] | None = None) -> dict:
     if args.fused_outer:
         if args.combine not in (None, "fused"):
             ap.error(f"--fused-outer conflicts with --combine "
-                     f"{args.combine}: the fused outer step IS the combine "
-                     f"backend")
+                     f"{args.combine}: the fused outer step does its own "
+                     f"combine")
         args.combine = "fused"
     if args.mesh_agents and args.agents not in (None, args.mesh_agents):
         ap.error(f"--agents {args.agents} conflicts with --mesh-agents "

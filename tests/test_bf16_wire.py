@@ -1,12 +1,13 @@
 """bf16 combine wire: dtype resolution rules and the u16 wire contract.
 
 The resolution tests run in-process.  The end-to-end test builds the
-mesh_sparse combines on a 4-forced-host-device agent mesh in a subprocess
-and checks the module-docstring contract in optimized HLO: the bf16 wire
-ships as 2-byte u16 collective-permutes (XLA:CPU's float normalization
-would silently re-widen raw bf16 permutes to f32 — the bitcast is what
-makes the halving real on every backend), totals exactly deg · bf16-shard
-bytes, and the mix stays within one bf16 rounding of the f64 reference.
+mesh_sparse_dynamic combine on a 4-forced-host-device agent mesh in a
+subprocess and checks the module-docstring contract in optimized HLO: the
+bf16 wire ships as 2-byte u16 collective-permutes (XLA:CPU's float
+normalization would silently re-widen raw bf16 permutes to f32 — the
+bitcast is what makes the halving real on every backend), totals exactly
+deg · bf16-shard bytes, and the mix stays within one bf16 rounding of the
+f64 reference.
 """
 import os
 import subprocess
@@ -72,7 +73,7 @@ SCRIPT = textwrap.dedent("""
     shard = 256 * 2                       # one agent's bf16 leaf block
 
     fn = jax.jit(diffusion.make_combine(
-        "mesh_sparse", topo.matrix, "agent", mesh=mesh,
+        "mesh_sparse_dynamic", topo.matrix, "agent", mesh=mesh,
         combine_dtype="bfloat16"))
     hlo = fn.lower(phi).compile().as_text()
     cp = HloCost(hlo, n_dev=K).collectives()["per_op"]["collective-permute"]

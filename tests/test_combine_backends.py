@@ -27,7 +27,7 @@ def test_dense_sparse_pallas_parity(topo, K):
     A = T.combination_matrix(K, topo)
     phi = _ragged_phi(K, seed=K)
     dense = D.make_combine("dense", A=A)(phi)
-    sparse = D.make_combine("sparse_host", A=A)(phi)
+    sparse = D.make_combine("sparse_host_dynamic", A=A)(phi)
     pallas = D.make_combine("pallas", A=A, interpret=True)(phi)
     for a, b, c in zip(jax.tree.leaves(dense), jax.tree.leaves(sparse),
                        jax.tree.leaves(pallas)):
@@ -95,11 +95,9 @@ def test_pallas_combine_inside_jit():
 # ---------------------------------------------------------------------------
 
 def test_registry_contains_all_backends():
-    names = D.combine_backends()
-    for expected in ("dense", "sparse_host", "sparse", "mesh_sparse",
-                     "sparse_host_dynamic", "sparse_dynamic",
-                     "mesh_sparse_dynamic", "pallas", "centralized", "none"):
-        assert expected in names
+    assert D.combine_backends() == (
+        "dense", "sparse_host_dynamic", "sparse_dynamic",
+        "mesh_sparse_dynamic", "pallas", "centralized", "none")
 
 
 def test_make_combine_rejects_unknown():
@@ -110,15 +108,27 @@ def test_make_combine_rejects_unknown():
 def test_select_backend_rules():
     assert D.select_backend(np.ones((1, 1))) == "none"
     ring = T.combination_matrix(8, "ring")
-    assert D.select_backend(ring) == "sparse_host"
+    assert D.select_backend(ring) == "sparse_host_dynamic"
     full = T.combination_matrix(8, "full")          # degree K-1: dense wins
     assert D.select_backend(full) in ("dense", "pallas")
-    # a live mesh whose agent axis matches K upgrades ring to mesh_sparse
+    # a live mesh whose agent axis matches K takes the shard_mapped rounds
     from repro.compat import abstract_mesh
     mesh = abstract_mesh((8, 2), ("data", "model"))
-    assert D.select_backend(ring, mesh=mesh, axis_name="data") == "mesh_sparse"
+    assert (D.select_backend(ring, mesh=mesh, axis_name="data")
+            == "mesh_sparse_dynamic")
     # mismatched extent falls back to the host roll
-    assert D.select_backend(ring, mesh=mesh, axis_name="model") == "sparse_host"
+    assert (D.select_backend(ring, mesh=mesh, axis_name="model")
+            == "sparse_host_dynamic")
+    # 'sparse' resolves by the mesh: host rolls off a mesh and on a legacy
+    # mesh with the agents on data, the shard_mapped rounds on an agent mesh
+    assert D.select_backend(ring, request="sparse") == "sparse_host_dynamic"
+    assert (D.select_backend(ring, mesh=mesh, axis_name="data",
+                             request="sparse") == "sparse_host_dynamic")
+    agent_mesh = abstract_mesh((8, 2), ("agent", "model"))
+    assert (D.select_backend(ring, mesh=agent_mesh, request="sparse")
+            == "mesh_sparse_dynamic")
+    # a registered name is itself
+    assert D.select_backend(ring, request="dense") == "dense"
 
 
 def test_auto_strategy_through_make_combine():
@@ -136,7 +146,7 @@ def test_combine_wire_bytes_model():
     ring = T.combination_matrix(K, "ring")
     mb = 1000
     assert D.combine_wire_bytes(ring, "none", mb) == 0
-    assert D.combine_wire_bytes(ring, "sparse_host", mb) == 2 * mb  # deg 2
+    assert D.combine_wire_bytes(ring, "sparse_host_dynamic", mb) == 2 * mb  # deg 2
     assert D.combine_wire_bytes(ring, "dense", mb) == (K - 1) * mb
     assert D.combine_wire_bytes(ring, "centralized", mb) == 2 * (K - 1) * mb // K
 

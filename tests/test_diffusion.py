@@ -39,7 +39,7 @@ def test_sparse_host_equals_dense(K, topo):
     A = T.combination_matrix(K, topo)
     phi = _phi(K, K)
     dense = D.dense_combine(jnp.asarray(A), phi)
-    sparse = D.sparse_combine_host(A, phi)
+    sparse = D.make_combine("sparse_host_dynamic", A=A)(phi)
     for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(sparse)):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
@@ -100,7 +100,8 @@ def test_disagreement_zero_for_identical_agents():
 def test_make_combine_factory():
     K = 4
     A = T.combination_matrix(K, "ring")
-    for name in ["dense", "sparse_host", "centralized", "none"]:
+    for name in ["dense", "sparse_host_dynamic", "sparse", "auto",
+                 "centralized", "none"]:
         fn = D.make_combine(name, A=A)
         out = fn(_phi(K))
         assert jax.tree.structure(out) == jax.tree.structure(_phi(K))

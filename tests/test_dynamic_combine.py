@@ -2,11 +2,10 @@
 
 Acceptance: the sparse_dynamic family reproduces the dense step-indexed
 einsum for every dynamic schedule kind on ring/full with ragged mixed-dtype
-pytrees, and the selection/resolution rules prefer the sparse lowering over
-the dense stacked fallback whenever the offset union is sparse.
+pytrees, every matrix backend takes a static matrix and a stacked schedule
+as given, and the selection rules prefer the sparse lowering over the dense
+stacked fallback whenever the offset union is sparse.
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -118,7 +117,7 @@ def test_sparse_host_dynamic_accepts_ir_and_static_matrix():
     # a static (K, K) matrix is the S=1 degenerate: step optional
     A = T.combination_matrix(K, "ring")
     static = D.make_combine("sparse_host_dynamic", A=A)
-    _assert_tree_close(static(phi), D.sparse_combine_host(A, phi))
+    _assert_tree_close(static(phi), D.dense_combine(jnp.asarray(A), phi))
 
 
 def test_dynamic_combine_requires_step_when_periodic():
@@ -129,12 +128,43 @@ def test_dynamic_combine_requires_step_when_periodic():
 
 
 # ---------------------------------------------------------------------------
-# Selection / resolution rules
+# Every matrix backend takes a static matrix and a stacked schedule as given
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["dense", "sparse_host_dynamic", "pallas",
+                                     "centralized", "none"])
+@pytest.mark.parametrize("kind", ["static", "gossip"])
+def test_backend_takes_static_matrix_and_stacked_schedule(kind, backend):
+    """A static ring matrix and a stacked gossip schedule (and the same
+    schedule as its ScheduleIR) go to the backend with no upgrade step:
+    matrix backends match the dense einsum at the same step, centralized
+    gives the centroid and none the identity."""
+    sched = _schedule(kind, "ring")
+    phi = _ragged_phi(K, seed=5)
+    step = 3
+    if backend == "centralized":
+        want = jax.tree.map(lambda x: jnp.broadcast_to(
+            jnp.mean(x.astype(jnp.float32), axis=0), x.shape), phi)
+    elif backend == "none":
+        want = phi
+    else:
+        want = D.dense_combine(jnp.asarray(sched.matrix_at(step)), phi)
+    for A in (sched.stacked(), sched.ir()):
+        got = D.make_combine(backend, A=A, interpret=True)(phi,
+                                                           jnp.int32(step))
+        _assert_tree_close(got, want)
+
+
+# ---------------------------------------------------------------------------
+# Selection rules
 # ---------------------------------------------------------------------------
 
 def test_select_backend_prefers_sparse_dynamic_for_stacked():
     ring = _schedule("link_failure", "ring").matrices
     assert D.select_backend(ring) == "sparse_host_dynamic"
+    # a stack of one static matrix is a schedule too
+    stack = np.stack([T.combination_matrix(K, "ring")] * 3)
+    assert D.select_backend(stack) == "sparse_host_dynamic"
     # dense offset union (full graph): the step-indexed einsum stays
     full = _schedule("link_failure", "full").matrices
     assert D.select_backend(full) == "dense"
@@ -145,31 +175,6 @@ def test_select_backend_prefers_sparse_dynamic_for_stacked():
                             axis_name="data") == "mesh_sparse_dynamic"
     assert D.select_backend(ring, mesh=mesh,
                             axis_name="model") == "sparse_host_dynamic"
-
-
-def test_resolve_upgrades_static_sparse_to_dynamic_sibling():
-    stack = _schedule("gossip", "ring").matrices
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")            # upgrade is silent
-        assert D.resolve_schedule_backend("sparse", stack) == "sparse_dynamic"
-        assert (D.resolve_schedule_backend("sparse_host", stack)
-                == "sparse_host_dynamic")
-        assert (D.resolve_schedule_backend("mesh_sparse", stack)
-                == "mesh_sparse_dynamic")
-        # matrix-free and already-capable backends pass through
-        assert D.resolve_schedule_backend("none", stack) == "none"
-        assert (D.resolve_schedule_backend("sparse_host_dynamic", stack)
-                == "sparse_host_dynamic")
-    # a static matrix never rewrites the choice
-    A = T.combination_matrix(K, "ring")
-    assert D.resolve_schedule_backend("sparse_host", A) == "sparse_host"
-
-
-def test_reject_stacked_points_at_dynamic_sibling():
-    stack = _schedule("round_robin", "ring").matrices
-    for name in ["sparse_host", "sparse", "mesh_sparse"]:
-        with pytest.raises(ValueError, match=f"{name}_dynamic|dynamic"):
-            D.make_combine(name, A=stack, axis_name="data", mesh="unused")
 
 
 def test_combine_wire_bytes_dynamic():
@@ -222,9 +227,8 @@ def test_trainer_sparse_dynamic_matches_dense_backend():
                                   jax.tree.map(jnp.asarray, qry))
         return state
 
-    # 'sparse_host' upgrades to 'sparse_host_dynamic' via
-    # resolve_schedule_backend inside make_meta_step
+    # off a mesh, 'sparse' resolves to 'sparse_host_dynamic'
     sa = run("dense")
-    sb = run("sparse_host")
+    sb = run("sparse")
     for a, b in zip(jax.tree.leaves(sa.params), jax.tree.leaves(sb.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)

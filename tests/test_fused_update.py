@@ -10,6 +10,8 @@ schedules.  f32 leaves are held to near-exact tolerance; bf16 leaves get a
 rounding-level budget — the fused path keeps the clipped gradient in fp32
 for the moment update where the unfused chain rounds it to bf16 first.
 """
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -189,19 +191,34 @@ def test_total_clip_freezes_nothing_but_zeroes_direction():
 
 
 def test_fused_backend_registered():
-    assert "fused" in diffusion.combine_backends()
-    # the combine-only face serves the cta pre-mix: must equal dense
+    """'fused' is a trainer mode, not a combine backend; the fused
+    trainer's cta pre-mix (it runs before the gradient, so it cannot fuse
+    with the update) is the pallas combine."""
+    assert "fused" not in diffusion.combine_backends()
     A = ring_table()
-    phi = ragged_params()
-    got = diffusion.make_combine("fused", A=A, interpret=True)(phi, 0)
-    want = diffusion.make_combine("dense", A=A)(phi, 0)
-    assert_tree_close(got, want)
-    # stacked schedules stay on the fused backend (step-indexed capable)
-    assert diffusion.resolve_schedule_backend(
-        "fused", ring_table(stacked=True)) == "fused"
+    with pytest.raises(ValueError, match="registered"):
+        diffusion.make_combine("fused", A=A)
     # 'auto' never volunteers the fused path — it changes optimizer wiring
     assert diffusion.select_backend(A) != "fused"
     assert diffusion.select_backend(ring_table(stacked=True)) != "fused"
+    make_combine = diffusion.make_combine
+    asked = []
+
+    def spy(name, *args, **kw):
+        asked.append(name)
+        return make_combine(name, *args, **kw)
+
+    mcfg = MetaConfig(num_agents=K, tasks_per_agent=2,
+                      update_config=UpdateConfig(strategy="cta",
+                                                 backend="fused"),
+                      topology_config=TopologyConfig(graph="ring"))
+    with mock.patch.object(diffusion, "make_combine", spy):
+        make_meta_step(lambda params, batch: 0.0, mcfg)
+    assert asked == ["pallas"]
+    phi = ragged_params()
+    got = make_combine("pallas", A=A, interpret=True)(phi, 0)
+    want = make_combine("dense", A=A)(phi, 0)
+    assert_tree_close(got, want)
 
 
 def test_unqualified_optimizer_raises():

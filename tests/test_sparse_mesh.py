@@ -32,8 +32,9 @@ SCRIPT = textwrap.dedent("""
             "b": jax.device_put(phi["b"], NamedSharding(mesh, P("data", None))),
         }
         specs = {"w": P("data", None, "model"), "b": P("data", None)}
-        sparse = diffusion.make_mesh_sparse_combine(A, mesh, "data",
-                                                    in_specs=specs)
+        sparse = diffusion.make_combine("mesh_sparse_dynamic", A=A,
+                                        mesh=mesh, axis_name="data",
+                                        in_specs=specs)
         out = jax.jit(sparse)(phi_sh)
         ref = diffusion.dense_combine(jnp.asarray(A), phi)
         for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
@@ -101,9 +102,11 @@ SCRIPT_2D = textwrap.dedent("""
         # select_backend must detect the agent axis on its own: a ring on
         # a 2D (agent, model) mesh routes to the shard_mapped backend
         # without the caller passing axis_name
-        assert diffusion.select_backend(A, mesh=mesh2d) == "mesh_sparse"
-        sparse = diffusion.make_combine("mesh_sparse", A=A, mesh=mesh2d,
-                                        axis_name="agent", in_specs=specs)
+        assert (diffusion.select_backend(A, mesh=mesh2d)
+                == "mesh_sparse_dynamic")
+        sparse = diffusion.make_combine("mesh_sparse_dynamic", A=A,
+                                        mesh=mesh2d, axis_name="agent",
+                                        in_specs=specs)
         out2d = jax.jit(sparse)(phi_sh)
         for a, b in zip(jax.tree.leaves(out2d), jax.tree.leaves(ref)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -133,8 +136,8 @@ SCRIPT_2D = textwrap.dedent("""
             k: jax.device_put(v, NamedSharding(mesh1d, specs1d[k]))
             for k, v in phi.items()
         }
-        sparse1d = diffusion.make_combine("mesh_sparse", A=A, mesh=mesh1d,
-                                          axis_name="agent",
+        sparse1d = diffusion.make_combine("mesh_sparse_dynamic", A=A,
+                                          mesh=mesh1d, axis_name="agent",
                                           in_specs=specs1d)
         out1d = jax.jit(sparse1d)(phi_1d)
     for a, b in zip(jax.tree.leaves(out1d), jax.tree.leaves(out2d)):

@@ -292,17 +292,6 @@ def test_dense_combine_indexes_stacked_schedule():
         combine(phi)                           # stacked schedule needs step
 
 
-def test_sparse_backends_reject_stacked_schedule():
-    stack = np.stack([topology.combination_matrix(K, "ring")] * 3)
-    for name in ["sparse_host", "sparse", "mesh_sparse"]:
-        # the error points at the dynamic sibling that CAN serve the stack
-        with pytest.raises(ValueError, match=f"{name}_dynamic"):
-            diffusion.make_combine(name, A=stack, axis_name="data",
-                                   mesh="unused")
-    # auto-selection prefers the sparse dynamic lowering over dense
-    assert diffusion.select_backend(stack) == "sparse_host_dynamic"
-
-
 def test_trainer_with_dynamic_schedules_contracts(sine_model, episodes):
     for schedule in ["link_failure", "gossip", "round_robin"]:
         mcfg = _nested("atc", schedule=schedule, link_failure_p=0.3)
@@ -328,10 +317,10 @@ def test_flat_fields_warn_and_map():
         m = MetaConfig(num_agents=4, combine="none")
     assert m.update_config.strategy == "none"
     with pytest.warns(DeprecationWarning):
-        m = MetaConfig(num_agents=4, combine="sparse_host", mode="fomaml",
+        m = MetaConfig(num_agents=4, combine="sparse", mode="fomaml",
                        combine_every=4)
     assert m.update_config == UpdateConfig(strategy="atc", inner="fomaml",
-                                           backend="sparse_host",
+                                           backend="sparse",
                                            combine_every=4)
 
 
@@ -389,24 +378,6 @@ def test_replace_on_flat_field_warns_about_conflict():
             base, update_config=dataclasses.replace(base.update_config,
                                                     backend="pallas"))
     assert cfg4.combine == "pallas"
-
-
-def test_schedule_backend_resolution_for_stacked():
-    stack = np.stack([topology.combination_matrix(K, "ring")] * 3)
-    # static sparse backends upgrade silently to their dynamic siblings
-    # (same permute rounds + wire cost, step-gathered weights); dense/auto
-    # and static matrices pass through untouched
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert diffusion.resolve_schedule_backend(
-            "mesh_sparse", stack) == "mesh_sparse_dynamic"
-        assert diffusion.resolve_schedule_backend(
-            "sparse_host", stack) == "sparse_host_dynamic"
-        assert diffusion.resolve_schedule_backend("dense", stack) == "dense"
-        assert diffusion.resolve_schedule_backend("auto", stack) == "auto"
-        assert diffusion.resolve_schedule_backend(
-            "mesh_sparse", topology.combination_matrix(K, "ring")
-        ) == "mesh_sparse"
 
 
 def test_topology_typo_rejected_even_at_k1():
